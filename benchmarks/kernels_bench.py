@@ -87,8 +87,9 @@ def _fused_vs_unfused(m: int, reps: int) -> dict:
                               block_rows=br4)
 
     def fused():
-        return fw.ternary_pack_2d(q4, p14, p24, 0.2, interpret=True,
-                                  block_rows=br4)
+        return fw.ternary_pack_stacked_2d(
+            q4[None], p14, p24, 2, 0.2, 0.0, interpret=True,
+            block_rows=br4, block_workers=1)[0]
 
     np.testing.assert_array_equal(np.asarray(unfused()), np.asarray(fused()))
     up_unfused = _bench(unfused, reps=reps)
@@ -154,7 +155,7 @@ def _batched_uplink(m: int, n_workers: int, reps: int,
     plan = tune.lookup("uplink_stacked", r4, n_workers, interpret=True)
 
     def loop():
-        return jnp.stack([ops.flat_ternary_pack_traced(
+        return jnp.stack([ops.flat_ternary_pack(
             bufs_q[i], p1, p2, t=3, beta=0.2, alpha1=0.01,
             interpret=True) for i in range(n_workers)])
 
@@ -264,7 +265,7 @@ def _tree_scaling(m: int, n_list: tuple, fanout: int, reps: int) -> list:
         p2 = jax.random.normal(jax.random.fold_in(k, 2), (rows, 128))
         w = jnp.full((n,), 1.0 / max(n - 1, 1)).at[0].set(0.0)
         if n <= 64:
-            # at larger N the cpu-interpret default (one-shot) is already
+            # at larger N the cpu-interpret default (single step) is already
             # the plan the sweep would pick; skip the expensive timing
             tune.autotune_stacked(r4, n, interpret=True, reps=1)
             tune.autotune_master(r4, n, interpret=True, reps=1)
@@ -517,7 +518,7 @@ def _scan_rounds_bench(m: int, n_workers: int, rounds: int,
     the Python loop re-dispatches both launches and returns control to the
     host every round.
 
-    NOTE on CPU wall time: since the tuned one-shot wire kernels landed,
+    NOTE on CPU wall time: since the tuned single-step wire plans landed,
     the jitted round body is ~4x faster, which leaves the interpret-mode
     scan's fixed carry overhead (the pallas while_loop buffers threaded
     through the lax.scan carry) as the visible cost — the scan can time
@@ -598,7 +599,8 @@ from repro.fed.distributed import build_fed_sync, fed_state_init
 
 m = int(sys.argv[1])
 reps = int(sys.argv[2])
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 F, MOD = 4, 2
 params = {"w": jax.random.normal(jax.random.PRNGKey(0), (m,))}
 sizes = jnp.linspace(50.0, 200.0, F)
@@ -611,7 +613,7 @@ state["params_prev"] = jax.tree_util.tree_map(lambda x: x + 0.01, params)
 state["prev_costs"] = jnp.ones((F,))
 
 out = {"params": m, "fed": F, "model": MOD, "mode": "cpu-interpret"}
-with mesh:
+with jax.set_mesh(mesh):
     for strat in ("fedpc_packed", "fedpc_reduce"):
         for shard in (True, False):
             layout = fl.layout_of(params, shards=MOD if shard else 1)
@@ -639,7 +641,16 @@ print("SYNC " + json.dumps(out))
 
 def _sharded_sync(m: int, reps: int) -> dict | None:
     """Sharded vs replicated fed sync on an 8-host-device subprocess mesh
-    (4 fed × 2 model): wall time per jitted sync + per-device wire bytes."""
+    (4 fed × 2 model): wall time per jitted sync + per-device wire bytes.
+
+    CPU only: the child needs JAX while this process already holds it, and
+    on a chip host the parent owns the chip — a JAX child there fails or
+    hangs, so the phase is refused instead."""
+    if jax.default_backend() != "cpu":
+        emit("sync_bench_skipped", 0.0,
+             f"host-device subprocess mesh is CPU-only "
+             f"(platform {jax.default_backend()})")
+        return None
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ, PYTHONPATH=src)
     proc = subprocess.run(
@@ -847,6 +858,9 @@ if __name__ == "__main__":
                          "candidate — BENCH_kernels.json provenance)")
     cli = ap.parse_args()
     from contextlib import ExitStack
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.kernels import tune as _tune
     from repro.telemetry import profile as _tprof

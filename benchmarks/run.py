@@ -18,6 +18,8 @@ import traceback
 def main() -> None:
     from benchmarks import (ablation, accuracy, comm, convergence,
                             kernels_bench, noniid, roofline)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     modules = [
         ("comm", comm),
         ("convergence", convergence),

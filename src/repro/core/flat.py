@@ -12,13 +12,15 @@ Layout
 ------
 Leaves are raveled in ``tree_flatten`` order and concatenated into one vector
 of ``n`` scalars, zero-padded to ``rows * 128`` with ``rows % ROW_MULTIPLE
-== 0``. ``ROW_MULTIPLE = 32`` guarantees every view the kernels need is
-aligned:
+== 0``. ``ROW_MULTIPLE = 256`` guarantees every view the kernels need is
+aligned, and that the chip's 64-row wire tile (``tune.BLOCK_ROWS``)
+divides the kernel views exactly, whatever the model's parameter count:
 
 * ``(rows, 128)``          — float32 buffer, 8-sublane aligned;
 * ``(rows // 4, 512)``     — the uplink kernel's input view (4 consecutive
   codes per output byte, matching §3.3 / ``core.packing.pack2bit`` order);
-* ``(rows // 4, 128)``     — the packed uint8 wire buffer, lane-aligned.
+* ``(rows // 4, 128)``     — the packed uint8 wire buffer, lane-aligned
+  (and a whole number of 32-row uint8 tiles).
 
 The zero padding is a fixed point of the whole wire path: ternarizing
 ``q = p1 = p2 = 0`` yields code 0, and the master update maps a zero tail to
@@ -49,7 +51,7 @@ import jax.numpy as jnp
 from repro.utils import PyTree, round_up
 
 LANES = 128
-ROW_MULTIPLE = 32          # keeps rows, rows//4 sublane-aligned (see above)
+ROW_MULTIPLE = 256         # rows//4 % 64 == 0: whole chip tiles (see above)
 PACK = 4                   # ternary codes per wire byte (§3.3)
 
 

@@ -58,18 +58,29 @@ class SyntheticLM:
     seed: int = 0
 
     def generate(self) -> np.ndarray:
+        """Sparse row-stochastic transitions (gamma(0.3) weights) give the
+        streams learnable structure. Each row is drawn only when the chain
+        first visits its state, from a generator keyed by (seed, state):
+        memory grows with the states visited, not with vocab² — a dense
+        matrix at a 50K-token vocabulary would take 20 GB."""
         rng = np.random.default_rng(self.seed)
-        # Sparse row-stochastic transition matrix → learnable structure.
-        trans = rng.gamma(0.3, 1.0, (self.vocab, self.vocab)).astype(np.float64)
-        trans /= trans.sum(axis=1, keepdims=True)
-        cum = np.cumsum(trans, axis=1)
+        rows: dict[int, np.ndarray] = {}
+
+        def cum_row(s: int) -> np.ndarray:
+            if s not in rows:
+                w = np.random.default_rng((self.seed, s)).gamma(
+                    0.3, 1.0, self.vocab)
+                rows[s] = np.cumsum(w / w.sum())
+            return rows[s]
+
         toks = np.zeros((self.n_sequences, self.seq_len), np.int32)
         state = rng.integers(0, self.vocab, self.n_sequences)
         for t in range(self.seq_len):
             toks[:, t] = state
             u = rng.random(self.n_sequences)
             state = np.array(
-                [np.searchsorted(cum[s], uu) for s, uu in zip(state, u)],
+                [np.searchsorted(cum_row(int(s)), uu)
+                 for s, uu in zip(state, u)],
                 dtype=np.int64,
             ).clip(0, self.vocab - 1)
         return toks

@@ -58,21 +58,6 @@ from repro.utils import PyTree
 from repro.sharding.specs import param_specs, wire_specs
 
 
-def _shard_map(body, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map (jax≥0.5 `jax.shard_map` vs 0.4 API)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=frozenset(manual_axes),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    # 0.4's `auto` lowering chokes on axis_index under SPMD; the flat wire
-    # buffers are replicated over every non-fed axis anyway, so running the
-    # other axes manually too is equivalent.
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # Sync strategies (shard_map bodies over (fed, model), on flat buffer slabs)
 # ---------------------------------------------------------------------------
@@ -240,7 +225,7 @@ def _sync_body(q_buf, p_prev, p_prev2, *, wire: rd.WirePath, k_star, w,
     if mode == "packed":
         # Fused uplink on the slab → uint8 §3.3 codes on the wire → fused
         # master over the gathered stack (in-register decode, Eq. (3)).
-        pk = wire.uplink_traced(q, p_prev, p_prev2, t=t, beta=beta_k)
+        pk = wire.uplink(q, p_prev, p_prev2, t=t, beta=beta_k)
         pk_all = jax.lax.all_gather(pk, fed_axis)     # (F, sr/4, 128)
         return wire.master(q_pilot, pk_all, wf, p_prev, p_prev2, t=t)
 
@@ -463,13 +448,16 @@ def build_fed_sync(model: Model, mesh: Mesh, fed_axis: str = "data",
                 alive=(av if masked_wire else None))
 
             specs = wire_specs(fed_axis, m_axis)
-            sharded_sync = _shard_map(
-                body, mesh,
+            # Manual over EVERY mesh axis: a Mosaic kernel cannot be
+            # auto-partitioned, even over a size-1 axis. Axes the wire does
+            # not shard hold replicated slabs, so each of their devices
+            # runs the identical body.
+            sharded_sync = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(specs["stacked"], specs["history"],
                           specs["history"]),
                 out_specs=specs["out"],
-                manual_axes={fed_axis} | ({m_axis} if m_axis else set()),
-            )
+                axis_names=frozenset(mesh.axis_names), check_vma=False)
             if (masked_wire and privacy.enforce
                     and not audit_state["done"]):
                 # §4.2 enforcement hook: audit what actually crosses the

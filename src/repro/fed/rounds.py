@@ -301,20 +301,13 @@ class WirePath:
     # -- fused kernel path over (rows, 128) slabs ---------------------------
 
     def uplink(self, buf_q: jax.Array, buf_p1: jax.Array, buf_p2: jax.Array,
-               *, t: int) -> jax.Array:
-        """One worker's §3.3 wire buffer (static round): (rows, 128) →
-        (rows//4, 128) uint8, one launch, no int8 intermediate."""
-        return ops.flat_ternary_pack(
-            buf_q, buf_p1, buf_p2, t=t, beta=self.cfg.beta,
-            alpha1=self.cfg.alpha1, interpret=self.interpret,
-            block_rows=self.block_rows)
-
-    def uplink_traced(self, buf_q: jax.Array, buf_p1: jax.Array,
-                      buf_p2: jax.Array, *, t, beta=None) -> jax.Array:
-        """Like :meth:`uplink` but ``t`` (and an optional per-worker
-        ``beta``) may be traced — the distributed sync's per-slab uplink."""
+               *, t, beta=None) -> jax.Array:
+        """One worker's §3.3 wire buffer: (rows, 128) → (rows//4, 128)
+        uint8, one launch, no int8 intermediate. ``t`` (and an optional
+        per-worker ``beta``) may be traced — the distributed sync's
+        per-slab uplink."""
         beta = self.cfg.beta if beta is None else beta
-        return ops.flat_ternary_pack_traced(
+        return ops.flat_ternary_pack(
             buf_q, buf_p1, buf_p2, t=t, beta=beta,
             alpha1=self.cfg.alpha1, interpret=self.interpret,
             block_rows=self.block_rows)
@@ -495,6 +488,60 @@ class WirePath:
             block_workers=self.block_workers)
         return new_buf, packed
 
+    def aggregate_masked(self, bufs_q: jax.Array, buf_p1: jax.Array,
+                         buf_p2: jax.Array, *, t, w: jax.Array, betas=None,
+                         pmask=None, alive=None
+                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Everything of a masked round before the root unmask: the masked
+        uplink, the post-fault drop of dead rows, the tree folds and the
+        dropout repair. Returns ``(y_top, wq, y)`` — the root's word
+        partials (their modular sum is the recovered aggregate
+        ``sum_k W_k field_k`` over the survivors, masks cancelled), the
+        surviving fixed-point weights, and the committed leaf uplinks."""
+        spec = self.privacy
+        y, wq = self.uplink_masked(bufs_q, buf_p1, buf_p2, t=t, w=w,
+                                   betas=betas, pmask=pmask)
+        repair = None
+        if alive is not None:
+            if spec.recovery_threshold is None:
+                raise ValueError(
+                    "fault injection on the privacy wire requires "
+                    "privacy.recovery_threshold (the Shamir t of the "
+                    "dropout-recovery dealing) to be set")
+            n = bufs_q.shape[0]
+            gsz = self.tree.fanout if self.tree is not None else None
+            alive_eff, dead_eff = pvr.effective_masks(
+                pmask, alive, spec.recovery_threshold, gsz, n)
+            # Post-uplink deaths: each dead row leaves the modular sum
+            # (its weighted fields AND its own net mask), taking its W_k
+            # out of the de-bias; what remains is the survivors'
+            # uncancelled masks toward the dead, repaired below.
+            y = jnp.where(alive_eff[:, None, None] > 0, y,
+                          jnp.zeros_like(y))
+            wq = jnp.where(alive_eff > 0, wq, jnp.zeros_like(wq))
+            if spec.masking_on:
+                i_idx, j_idx = pvr.repair_pair_index(n, gsz)
+                keys = pvm.pair_stream_keys(spec.mask_seed, n, t)
+                if self.tree is not None:
+                    signs = pvm.tree_pair_signs(n, self.tree.fanout,
+                                                participation=pmask)
+                else:
+                    signs = pvm.pair_signs(n, participation=pmask)
+                repair = pvr.repair_coefficients(
+                    keys, signs, alive_eff, dead_eff, i_idx, j_idx)
+        y_top = (self._tree_fold_masked(y, t=t, pmask=pmask)
+                 if self.tree is not None else y)
+        if repair is not None:
+            # Modular sums commute, so leaf-level residue rides the tree
+            # unchanged and ONE fused launch at the root repairs every
+            # surviving-toward-dead stream. The repair lands in a static
+            # row: even a zeroed dead row still participates in the
+            # master's modular sum.
+            y_top = y_top.at[0].set(ops.flat_mask_repair(
+                y_top[0], repair[0], repair[1],
+                interpret=self.interpret, block_rows=self.block_rows))
+        return y_top, wq, y
+
     def round_from_stacked(self, bufs_q: jax.Array, k_star, w: jax.Array,
                            buf_p1: jax.Array, buf_p2: jax.Array, *, t,
                            betas=None, pmask=None, alive=None
@@ -520,50 +567,9 @@ class WirePath:
         buffers ride along for byte accounting / ledger purposes.
         """
         if self.privacy is not None and self.privacy.active:
-            spec = self.privacy
-            y, wq = self.uplink_masked(bufs_q, buf_p1, buf_p2, t=t, w=w,
-                                       betas=betas, pmask=pmask)
-            repair = None
-            if alive is not None:
-                if spec.recovery_threshold is None:
-                    raise ValueError(
-                        "fault injection on the privacy wire requires "
-                        "privacy.recovery_threshold (the Shamir t of the "
-                        "dropout-recovery dealing) to be set")
-                n = bufs_q.shape[0]
-                gsz = self.tree.fanout if self.tree is not None else None
-                alive_eff, dead_eff = pvr.effective_masks(
-                    pmask, alive, spec.recovery_threshold, gsz, n)
-                # Post-uplink deaths: each dead row leaves the modular sum
-                # (its weighted fields AND its own net mask), taking its
-                # W_k out of the de-bias; what remains is the survivors'
-                # uncancelled masks toward the dead, repaired below.
-                y = jnp.where(alive_eff[:, None, None] > 0, y,
-                              jnp.zeros_like(y))
-                wq = jnp.where(alive_eff > 0, wq, jnp.zeros_like(wq))
-                if spec.masking_on:
-                    i_idx, j_idx = pvr.repair_pair_index(n, gsz)
-                    keys = pvm.pair_stream_keys(spec.mask_seed, n, t)
-                    if self.tree is not None:
-                        signs = pvm.tree_pair_signs(n, self.tree.fanout,
-                                                    participation=pmask)
-                    else:
-                        signs = pvm.pair_signs(n, participation=pmask)
-                    repair = pvr.repair_coefficients(
-                        keys, signs, alive_eff, dead_eff, i_idx, j_idx)
-            if self.tree is not None:
-                y_top = self._tree_fold_masked(y, t=t, pmask=pmask)
-            else:
-                y_top = y
-            if repair is not None:
-                # Modular sums commute, so leaf-level residue rides the
-                # tree unchanged and ONE fused launch at the root repairs
-                # every surviving-toward-dead stream. The repair lands in a
-                # static row: even a zeroed dead row still participates in
-                # the master's modular sum.
-                y_top = y_top.at[0].set(ops.flat_mask_repair(
-                    y_top[0], repair[0], repair[1],
-                    interpret=self.interpret, block_rows=self.block_rows))
+            y_top, wq, y = self.aggregate_masked(
+                bufs_q, buf_p1, buf_p2, t=t, w=w, betas=betas, pmask=pmask,
+                alive=alive)
             buf_pilot = jnp.take(bufs_q, k_star, axis=0)
             new_buf = self.master_masked(buf_pilot, y_top, wq, buf_p1,
                                          buf_p2, t=t)

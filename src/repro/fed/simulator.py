@@ -30,9 +30,10 @@ TPU-mesh counterpart with the same math as collectives is
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +89,12 @@ class SimResult:
                      + np.sum(self.recovery_bytes_per_round))
 
 
+class ScanProgram(NamedTuple):
+    """One compiled multi-round scan program and its compile seconds."""
+    compiled: Any
+    compile_s: float
+
+
 def _should_donate() -> bool:
     """Donate the RoundState buffers into the jitted step where the backend
     honours donation (CPU silently copies and warns, so skip it there)."""
@@ -117,6 +124,10 @@ class FedSimulator:
         self.eval_fn = eval_fn
         self.ledger = LeakageLedger()
         self.evade_streak = evade_streak  # 0 = defence off
+        # Compiled run_fedpc_scan programs by (rounds, wire, has-masks,
+        # has-betas): reused by every later run of that shape (a resumed
+        # run does not recompile); their HLO is what a chip check inspects.
+        self.scan_programs: dict[tuple, ScanProgram] = {}
 
     # ------------------------------------------------------------------
     # FedPC shared plumbing
@@ -556,8 +567,45 @@ class FedSimulator:
             (w.opt_state, jnp.asarray(w.step, jnp.int32))
             for w in self.workers)
         masks_dev = None if masks is None else jnp.asarray(masks)
-        sizes = jnp.asarray(self.sizes)
+        args = (state, worker_carry, tuple(index_schedules), tuple(shards),
+                masks_dev, jnp.asarray(self.sizes), betas_arr,
+                jnp.asarray(t0, jnp.int32))
+        key = (rounds, wire, masks is None, betas_arr is None)
+        prog = self.scan_programs.get(key)
+        if prog is None:
+            t_c = time.perf_counter()
+            # The history state and the workers' optimizer carry are both
+            # replaced by the program's outputs: donate them.
+            compiled = jax.jit(
+                partial(self._scan_body, wire, layout, rounds),
+                donate_argnums=(0, 1) if _should_donate() else ()
+            ).lower(*args).compile()
+            prog = ScanProgram(compiled, time.perf_counter() - t_c)
+            self.scan_programs[key] = prog
+        state, worker_carry, infos = prog.compiled(*args)
 
+        # write back worker state (host bookkeeping, once)
+        for k, w in enumerate(self.workers):
+            w.opt_state = worker_carry[k][0]
+            part = rounds if masks is None else int(np.sum(masks[:, k] > 0))
+            w.step += steps_per_round[k] * part
+
+        k_stars = list(infos["k_star"])
+        raw_costs = list(infos["costs"])
+        return self._finish_fedpc(res, state, layout, t0, k_stars,
+                                  raw_costs, masks, model_bytes,
+                                  ledger_done=False,
+                                  records=infos["telemetry"],
+                                  driver="run_fedpc_scan")
+
+    def _scan_body(self, wire: rd.WirePath, layout: fl.FlatLayout,
+                   rounds: int, state, worker_carry, index_schedules,
+                   shards, masks_dev, sizes, betas_arr, t0):
+        """The whole multi-round program: ``rounds`` rounds of local
+        training + ``round_step`` as one ``lax.scan``. Every per-run operand
+        (schedules, shards, masks, the starting round) is an argument, so
+        one compiled program serves every run of the same shape — a resumed
+        run does not recompile."""
         def worker_fn(wc, buf, t):
             params = fl.unflatten_tree(buf, layout)
             r = t - t0                        # row into the schedules
@@ -588,26 +636,8 @@ class FedSimulator:
                 costs.append(cost_k)
             return tuple(new_wc), jnp.stack(bufs), jnp.stack(costs)
 
-        run = jax.jit(
-            lambda st, wc: rd.scan_rounds(
-                wire, st, worker_fn, wc, rounds, sizes,
-                betas=betas_arr, masks=masks_dev),
-            donate_argnums=(0,) if _should_donate() else ())
-        state, worker_carry, infos = run(state, worker_carry)
-
-        # write back worker state (host bookkeeping, once)
-        for k, w in enumerate(self.workers):
-            w.opt_state = worker_carry[k][0]
-            part = rounds if masks is None else int(np.sum(masks[:, k] > 0))
-            w.step += steps_per_round[k] * part
-
-        k_stars = list(infos["k_star"])
-        raw_costs = list(infos["costs"])
-        return self._finish_fedpc(res, state, layout, t0, k_stars,
-                                  raw_costs, masks, model_bytes,
-                                  ledger_done=False,
-                                  records=infos["telemetry"],
-                                  driver="run_fedpc_scan")
+        return rd.scan_rounds(wire, state, worker_fn, worker_carry, rounds,
+                              sizes, betas=betas_arr, masks=masks_dev)
 
     # ------------------------------------------------------------------
     # FedAvg baseline

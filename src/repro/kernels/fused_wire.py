@@ -1,31 +1,22 @@
 """Fused Pallas TPU kernels for the FedPC flat wire path.
 
-Two kernel families cover the whole per-round wire cost over the
+Two kernels cover the whole per-round plaintext wire cost over the
 ``FlatParams`` buffer (``repro.core.flat``):
 
-``ternary_pack_2d`` / ``ternary_pack_round1_2d`` — worker uplink. Fuses
-Eq. (5) (resp. Eq. (4)) ternarization *directly* into the §3.3 2-bit packed
-wire format: float (R, 512) history views in, uint8 (R, 128) packed codes
-out. The separate int8 code tensor of the two-kernel composition
-(``ternary_encode`` → ``pack2bit``) — 4× the wire size, written to and
-re-read from HBM — never exists: codes live only in VMEM registers.
-``ternary_pack_any_2d`` carries the round index as a scalar operand so a
-traced ``t`` selects the Eq. (4)/(5) branch in-register (for jit'd round
-loops).
-
-``ternary_pack_stacked_2d`` batches all N workers' uplinks into ONE launch
-over a (N, R, 512) stack. The grid is **rows-major with the worker axis
-minor**: for one row block the kernel steps through consecutive worker
-blocks, so the shared ``p1``/``p2`` history blocks keep the same block index
-across those steps and are fetched once per row block instead of once per
-(worker, row) step — N× less history traffic than the old worker-major
-order. ``block_workers`` workers ride in each block (vectorized, still
-register-only); when the plan collapses to one step (``block_rows == R`` and
-``block_workers == N`` — the cpu-interpret optimum, where per-step
-machinery dominates) the launch drops the grid entirely. The Eq. (5)
-threshold may be a per-worker ``(N,)`` beta vector (heterogeneous beta_k):
-it rides as a (N, 1) operand blocked over the worker axis — no dynamic
-in-kernel indexing.
+``ternary_pack_stacked_2d`` — the worker uplink, all N workers in ONE
+launch over a (N, R, 512) stack. Fuses Eq. (4)/(5) ternarization *directly*
+into the §3.3 2-bit packed wire format: float history views in, uint8
+(N, R, 128) packed codes out. No int8 code tensor — 4× the wire size —
+ever reaches HBM: codes live only in VMEM registers. The round index ``t``
+rides as a scalar operand, so a traced ``t`` selects the Eq. (4)/(5)
+branch in-register and one compiled kernel serves every round; a single
+worker's uplink is the same kernel at N = 1. The grid is **rows-major
+with the worker axis minor**: for one row block the kernel steps through
+consecutive worker blocks, so the shared ``p1``/``p2`` history blocks keep
+the same block index across those steps and are fetched once per row
+block instead of once per (worker, row) step. The Eq. (5) threshold may be
+a per-worker ``(N,)`` beta vector (heterogeneous beta_k) read from SMEM at
+the grid's worker index.
 
 ``packed_master_update_2d`` — master downlink side of Eq. (3). Consumes the
 *packed* uint8 codes of all N workers on a 2-D ``(rows, workers)`` grid and
@@ -34,10 +25,11 @@ output's block index ignores the (minor) worker axis, so it stays resident
 in VMEM while the grid walks the workers, collecting Σ_k w_k T_k in place;
 the final worker step folds in the Eq. (3) combine (q − coeff·mult). VMEM
 per step is O(block) — independent of N — so federations scale past the
-paper's 10 nodes without growing the tile. The 2-bit decode is bit
-arithmetic on the packed byte (broadcast divide by powers of four — no
-``jnp.stack``, no (N, R, 128, 4) intermediate) with the per-worker ``w[k]``
-multiply folded straight into the decoded field. Worker contributions are
+paper's 10 nodes without growing the tile. The 2-bit decode replicates
+each byte over its four lanes (an MXU matmul, the inverse of the pack) and
+shifts each lane's field out in-register — no (N, R, 128, 4) intermediate
+— with the per-worker ``w[k]`` multiply folded straight into the decoded
+field. Worker contributions are
 accumulated strictly sequentially (k = 0..N−1), so the result is **bitwise
 invariant across every (block_rows, block_workers) plan** — autotuning can
 never change the math (``kernels.ref.packed_master_accum_ref`` is the
@@ -47,9 +39,17 @@ so the round index may be traced.
 
 Layout: the flat (rows, 128) buffer is viewed as (rows/4, 512) so that the
 four *consecutive* codes forming each wire byte sit in the last axis —
-exactly the §3.3 / ``core.packing.pack2bit`` byte order. Shifts are
-multiplies/divides by powers of two (VPU-safe, exact for 2-bit fields);
-the pack runs in float (exact for the 0..170 byte range), one cast out.
+exactly the §3.3 / ``core.packing.pack2bit`` byte order. Gathering four
+adjacent lanes into one byte is a lane compaction the TPU's vector unit
+cannot express (no lane-splitting reshape, no lane-strided load), so the
+pack runs on the MXU as a matmul against a 0/4^e selection matrix — exact,
+since fields, weights and byte values (≤ 170) are all integers that bf16
+holds exactly and the MXU accumulates in f32.
+
+Scalars (``t``, thresholds) and per-worker vectors (beta_k, w_k) live in
+SMEM and are read at the grid's worker index; blocks span whole row tiles,
+so every block obeys the chip's (8, 128) tiling. The same kernels run in
+the Pallas interpreter on the CPU (``tune.use_interpreter`` decides).
 
 Block sizes: callers normally leave ``block_rows``/``block_workers`` to the
 ``repro.kernels.tune`` autotuner (via the ``ops`` wrappers); the module
@@ -62,24 +62,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 PACK = 4
 BLOCK_ROWS = 64            # (64, 512) fp32 tile = 128 KiB per input
 BLOCK_WORKERS = 1          # one worker per step → master VMEM is O(block)
 
-
-def _codes_eq5(q, p1, p2, beta):
-    """Eq. (5) codes in-register: float tiles → float {-1, 0, +1}."""
-    step = p1 - p2
-    delta = q - p1
-    significant = jnp.abs(delta) >= beta * jnp.abs(step)
-    return jnp.where(significant, jnp.sign(delta * step), 0.0)
-
-def _codes_eq4(q, p0, alpha):
-    """Eq. (4) round-1 codes in-register vs the public init P^0."""
-    d = q - p0
-    return (d > alpha).astype(jnp.float32) - (d < -alpha).astype(jnp.float32)
+# Whole-array SMEM operand: scalars and per-worker vectors, indexed in-kernel.
+SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _codes_any(q, p1, p2, t, beta, alpha1):
@@ -97,68 +88,65 @@ def _codes_any(q, p1, p2, t, beta, alpha1):
 
 
 def _pack_tile(codes):
-    """(..., 512) float codes → (..., 128) uint8, 4 consecutive codes/byte.
+    """(R, 512) float codes → (R, 128) uint8, 4 consecutive codes/byte.
 
-    Packed in float (biased fields 0..2, byte value ≤ 170 — exact in fp32)
-    with a single cast out: one dtype conversion instead of the int32
-    round-trip, measurably faster on XLA:CPU and identical bits.
+    byte[j] = Σ_e 4^e·(code[4j+e] + 1): a (512, 128) selection matrix with
+    4^e at row 4j+e of column j turns the lane compaction into one MXU
+    matmul. Every operand is a small integer (exact in bf16) and the f32
+    accumulation of at most 170 is exact, so this is bit-identical to
+    shift-and-or packing on every backend.
     """
-    lead = codes.shape[:-1]
-    b = (codes + 1.0).reshape(*lead, LANES, PACK)
-    byte = b[..., 0] + b[..., 1] * 4.0 + b[..., 2] * 16.0 + b[..., 3] * 64.0
-    return byte.astype(jnp.uint8)
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANES * PACK, LANES), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (LANES * PACK, LANES), 1)
+    sel = jnp.where(jax.lax.shift_right_logical(r, 2) == j,
+                    jax.lax.shift_left(1, 2 * (r & 3)), 0)
+    byte = jnp.dot((codes + 1.0).astype(jnp.bfloat16),
+                   sel.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return byte.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _fields(b):
+    """(R, 128) packed bytes → (R, 512) int32 biased 2-bit fields {0, 1, 2}.
+
+    The inverse lane expansion also runs on the MXU: a 0/1 matrix
+    replicates byte j over lanes 4j..4j+3 (exact — bytes ≤ 255 are bf16
+    integers), then each lane shifts out its own field by its lane index.
+    """
+    j = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES * PACK), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES * PACK), 1)
+    rep_sel = (jax.lax.shift_right_logical(c, 2) == j).astype(jnp.bfloat16)
+    rep = jnp.dot(b.astype(jnp.int32).astype(jnp.bfloat16), rep_sel,
+                  preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, rep.shape, 1)
+    return jax.lax.shift_right_logical(rep, 2 * (lane & 3)) & 3
 
 
 def _weighted_decode(b, w):
-    """(R, 128) packed byte + scalar w → (R, 512) float32 ``w · code``.
-
-    Pure bit arithmetic on the byte: a broadcast divide by [1, 4, 16, 64]
-    (powers of four built from a shifted iota — VPU-safe, no closed-over
-    array constant) isolates the four 2-bit fields, and the ``w`` multiply
-    is folded into the de-bias (``w·field − w`` = ``w·(field − 1)``) so the
-    bare {-1, 0, 1} code tensor never materializes.
-    """
-    bi = b.astype(jnp.int32)[:, :, None]                   # (R, 128, 1)
-    e = jax.lax.broadcasted_iota(jnp.int32, (1, 1, PACK), 2)
-    fields = (bi // jax.lax.shift_left(jnp.int32(1), 2 * e)) % 4
-    wf = fields.astype(jnp.float32) * w - w                # w · (field − 1)
-    return wf.reshape(b.shape[0], LANES * PACK)
-
-
-def _ternary_pack_kernel(q_ref, p1_ref, p2_ref, beta_ref, out_ref):
-    q = q_ref[...].astype(jnp.float32)
-    p1 = p1_ref[...].astype(jnp.float32)
-    p2 = p2_ref[...].astype(jnp.float32)
-    out_ref[...] = _pack_tile(_codes_eq5(q, p1, p2, beta_ref[0]))
-
-
-def _ternary_pack_round1_kernel(q_ref, p0_ref, alpha_ref, out_ref):
-    q = q_ref[...].astype(jnp.float32)
-    p0 = p0_ref[...].astype(jnp.float32)
-    out_ref[...] = _pack_tile(_codes_eq4(q, p0, alpha_ref[0]))
-
-
-def _ternary_pack_any_kernel(q_ref, p1_ref, p2_ref, scal_ref, out_ref):
-    q = q_ref[...].astype(jnp.float32)
-    p1 = p1_ref[...].astype(jnp.float32)
-    p2 = p2_ref[...].astype(jnp.float32)
-    t, beta, alpha1 = scal_ref[0], scal_ref[1], scal_ref[2]
-    out_ref[...] = _pack_tile(_codes_any(q, p1, p2, t, beta, alpha1))
+    """(R, 128) packed byte + scalar w → (R, 512) float32 ``w · code``,
+    with the ``w`` multiply folded into the de-bias (``w·field − w`` =
+    ``w·(field − 1)``) so the bare {-1, 0, 1} code tensor never
+    materializes."""
+    return _fields(b).astype(jnp.float32) * w - w
 
 
 def _stacked_kernel(q_ref, p1_ref, p2_ref, beta_ref, scal_ref, out_ref):
-    """One (block_workers, block_rows) step of the stacked uplink —
-    vectorized over the worker-block axis, shared history broadcast."""
+    """One (block_workers, block_rows) step of the stacked uplink: each
+    resident worker ternarizes against the shared history block with its
+    own beta_k (SMEM, at the grid's worker index) and packs in-register."""
+    bw = q_ref.shape[0]
+    w0 = pl.program_id(1) * bw
     t, alpha1 = scal_ref[0], scal_ref[1]
-    q = q_ref[...].astype(jnp.float32)                 # (bw, br, 512)
-    p1 = p1_ref[...].astype(jnp.float32)[None]         # shared history block
-    p2 = p2_ref[...].astype(jnp.float32)[None]
-    beta = beta_ref[...].astype(jnp.float32)[:, :, None]   # (bw, 1, 1)
-    out_ref[...] = _pack_tile(_codes_any(q, p1, p2, t, beta, alpha1))
+    p1 = p1_ref[...].astype(jnp.float32)               # shared history block
+    p2 = p2_ref[...].astype(jnp.float32)
+    for j in range(bw):
+        q = q_ref[j].astype(jnp.float32)
+        out_ref[j] = _pack_tile(
+            _codes_any(q, p1, p2, t, beta_ref[w0 + j], alpha1))
 
 
 def _master_accum_kernel(q_ref, pk_ref, w_ref, p1_ref, p2_ref, scal_ref,
-                         out_ref, *, block_workers: int, last_k: int):
+                         out_ref):
     """One (row block, worker block) step of the accumulating master.
 
     The output block is revisited across the (minor) worker axis: step
@@ -166,17 +154,18 @@ def _master_accum_kernel(q_ref, pk_ref, w_ref, p1_ref, p2_ref, scal_ref,
     strictly ascending order, and the last worker step applies Eq. (3).
     """
     k = pl.program_id(1)
+    bw = pk_ref.shape[0]
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     acc = out_ref[...].astype(jnp.float32)
-    for j in range(block_workers):                     # sequential: bitwise
-        acc = acc + _weighted_decode(pk_ref[j], w_ref[j, 0])
+    for j in range(bw):                                # sequential: bitwise
+        acc = acc + _weighted_decode(pk_ref[j], w_ref[k * bw + j])
     out_ref[...] = acc.astype(out_ref.dtype)
 
-    @pl.when(k == last_k)
+    @pl.when(k == pl.num_programs(1) - 1)
     def _combine():
         t, alpha0 = scal_ref[0], scal_ref[1]
         step = (p1_ref[...].astype(jnp.float32)
@@ -187,143 +176,38 @@ def _master_accum_kernel(q_ref, pk_ref, w_ref, p1_ref, p2_ref, scal_ref,
         out_ref[...] = (q - coeff * mult).astype(out_ref.dtype)
 
 
-def _master_oneshot_kernel(q_ref, pk_ref, w_ref, p1_ref, p2_ref, scal_ref,
-                           out_ref, *, n_workers: int):
-    """Single-step master (cpu-interpret plan): same strictly-sequential
-    worker accumulation as the grid kernel — bitwise identical output."""
-    acc = jnp.zeros((q_ref.shape[0], LANES * PACK), jnp.float32)
-    for j in range(n_workers):
-        acc = acc + _weighted_decode(pk_ref[j], w_ref[j, 0])
-    t, alpha0 = scal_ref[0], scal_ref[1]
-    step = p1_ref[...].astype(jnp.float32) - p2_ref[...].astype(jnp.float32)
-    mult = jnp.where(t <= 1.0, alpha0, step)
-    q = q_ref[...].astype(jnp.float32)
-    out_ref[...] = (q - acc * mult).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def ternary_pack_2d(q, p1, p2, beta, *, interpret: bool = True,
-                    block_rows: int = BLOCK_ROWS):
-    """q/p1/p2 (R, 512) float, R % block_rows == 0 → uint8 (R, 128).
-
-    Equals ``pack2bit_2d(ternary_encode_2d(q, p1, p2, beta))`` with zero
-    int8 HBM intermediate and a single launch.
-    """
-    rows = q.shape[0]
-    grid = (rows // block_rows,)
-    in_spec = pl.BlockSpec((block_rows, LANES * PACK), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _ternary_pack_kernel,
-        grid=grid,
-        in_specs=[in_spec, in_spec, in_spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint8),
-        interpret=interpret,
-    )(q, p1, p2, jnp.asarray([beta], jnp.float32))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def ternary_pack_round1_2d(q, p0, alpha, *, interpret: bool = True,
-                           block_rows: int = BLOCK_ROWS):
-    """Round-1 (Eq. (4)) variant of :func:`ternary_pack_2d`."""
-    rows = q.shape[0]
-    grid = (rows // block_rows,)
-    in_spec = pl.BlockSpec((block_rows, LANES * PACK), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _ternary_pack_round1_kernel,
-        grid=grid,
-        in_specs=[in_spec, in_spec, pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint8),
-        interpret=interpret,
-    )(q, p0, jnp.asarray([alpha], jnp.float32))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def ternary_pack_any_2d(q, p1, p2, t, beta, alpha1, *, interpret: bool = True,
-                        block_rows: int = BLOCK_ROWS):
-    """Traced-round fused uplink: Eq. (4) at t <= 1, Eq. (5) after.
-
-    Same layout as :func:`ternary_pack_2d`, but the round index ``t`` (and
-    both thresholds) travel as scalar operands so one compiled kernel serves
-    every round — required inside jit'd round loops (the distributed sync)
-    where ``t`` is traced.
-    """
-    rows = q.shape[0]
-    grid = (rows // block_rows,)
-    in_spec = pl.BlockSpec((block_rows, LANES * PACK), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    scal = jnp.stack([jnp.asarray(t, jnp.float32),
-                      jnp.asarray(beta, jnp.float32),
-                      jnp.asarray(alpha1, jnp.float32)])
-    return pl.pallas_call(
-        _ternary_pack_any_kernel,
-        grid=grid,
-        in_specs=[in_spec, in_spec, in_spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint8),
-        interpret=interpret,
-    )(q, p1, p2, scal)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows",
                                              "block_workers"))
-def ternary_pack_stacked_2d(q, p1, p2, t, beta, alpha1, *,
-                            interpret: bool = True,
+def ternary_pack_stacked_2d(q, p1, p2, t, beta, alpha1, *, interpret: bool,
                             block_rows: int = BLOCK_ROWS,
                             block_workers: int = BLOCK_WORKERS):
     """Batched uplink: all N workers' wire buffers from ONE launch.
 
     q (N, R, 512) — every worker's history view; p1/p2 (R, 512) — the shared
-    public history passed once, not stacked N times. Grid is
-    (R/block_rows, N/block_workers) — **rows-major, worker minor**, so the
-    history blocks keep their block index across the consecutive worker
-    steps of one row block and are re-fetched per *row block*, not per
-    (worker, row) step. Blocks are vectorized over ``block_workers``
-    workers; ``block_rows == R`` with ``block_workers == N`` collapses to a
-    grid-less single-step launch (the cpu-interpret optimum — see
-    ``repro.kernels.tune``). Every plan packs bitwise-identically (the math
-    is elementwise).
+    public history passed once, not stacked N times (P^0 in the p1 slot at
+    t <= 1). Grid is (R/block_rows, N/block_workers) — **rows-major, worker
+    minor**, so the history blocks keep their block index across the
+    consecutive worker steps of one row block and are re-fetched per *row
+    block*, not per (worker, row) step. Every plan packs
+    bitwise-identically (the math is elementwise).
 
-    ``beta`` is either one scalar (shared threshold) or a ``(N,)`` vector of
-    per-worker beta_k — worker k's blocks read ``beta[k]`` via the blocked
-    (block_workers, 1) operand. Returns (N, R, 128) uint8.
+    ``t`` may be traced; ``beta`` is either one scalar (shared threshold) or
+    a ``(N,)`` vector of per-worker beta_k. Returns (N, R, 128) uint8.
     """
     n, rows, _ = q.shape
-    betas = jnp.broadcast_to(
-        jnp.asarray(beta, jnp.float32).reshape(-1, 1), (n, 1))
+    betas = jnp.broadcast_to(jnp.asarray(beta, jnp.float32).reshape(-1), (n,))
     scal = jnp.stack([jnp.asarray(t, jnp.float32),
                       jnp.asarray(alpha1, jnp.float32)])
-    if block_rows >= rows and block_workers >= n:
-        # One step: whole-operand blocks, no grid — skips the per-step
-        # block machinery entirely (interpret mode pays it per step).
-        return pl.pallas_call(
-            _stacked_kernel,
-            in_specs=[pl.BlockSpec(q.shape, None),
-                      pl.BlockSpec(p1.shape, None),
-                      pl.BlockSpec(p2.shape, None),
-                      pl.BlockSpec(betas.shape, None),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((n, rows, LANES), None),
-            out_shape=jax.ShapeDtypeStruct((n, rows, LANES), jnp.uint8),
-            interpret=interpret,
-        )(q, p1, p2, betas, scal)
     grid = (rows // block_rows, n // block_workers)
     q_spec = pl.BlockSpec((block_workers, block_rows, LANES * PACK),
                           lambda i, k: (k, i, 0))
     h_spec = pl.BlockSpec((block_rows, LANES * PACK), lambda i, k: (i, 0))
-    beta_spec = pl.BlockSpec((block_workers, 1), lambda i, k: (k, 0))
     out_spec = pl.BlockSpec((block_workers, block_rows, LANES),
                             lambda i, k: (k, i, 0))
     return pl.pallas_call(
         _stacked_kernel,
         grid=grid,
-        in_specs=[q_spec, h_spec, h_spec, beta_spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[q_spec, h_spec, h_spec, SMEM, SMEM],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n, rows, LANES), jnp.uint8),
         interpret=interpret,
@@ -333,7 +217,7 @@ def ternary_pack_stacked_2d(q, p1, p2, t, beta, alpha1, *,
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows",
                                              "block_workers"))
 def packed_master_update_2d(q_pilot, packed, w, p1, p2, t, alpha0, *,
-                            interpret: bool = True,
+                            interpret: bool,
                             block_rows: int = BLOCK_ROWS,
                             block_workers: int = BLOCK_WORKERS):
     """Fused Eq. (3) over packed wire codes, grid-accumulated over workers.
@@ -348,42 +232,23 @@ def packed_master_update_2d(q_pilot, packed, w, p1, p2, t, alpha0, *,
     *is* the resident output block: VMEM per step is
     ``(3 float + 1 out) · block_rows·512·4B + block_workers·block_rows·128B``
     — independent of N at the default ``block_workers = 1``, which is what
-    lets N = 64+ federations run without growing the tile (the old kernel
-    held all N packed blocks at once). Workers accumulate strictly
-    sequentially regardless of the (block_rows, block_workers) plan, so
-    every plan is bitwise-identical to
+    lets N = 64+ federations run without growing the tile. Workers
+    accumulate strictly sequentially regardless of the (block_rows,
+    block_workers) plan, so every plan is bitwise-identical to
     ``kernels.ref.packed_master_accum_ref``.
     """
     n, rows, _ = packed.shape
-    w2 = w.astype(jnp.float32).reshape(n, 1)
     scal = jnp.stack([jnp.asarray(t, jnp.float32),
                       jnp.asarray(alpha0, jnp.float32)])
-    if block_rows >= rows and block_workers >= n:
-        return pl.pallas_call(
-            functools.partial(_master_oneshot_kernel, n_workers=n),
-            in_specs=[pl.BlockSpec(q_pilot.shape, None),
-                      pl.BlockSpec(packed.shape, None),
-                      pl.BlockSpec(w2.shape, None),
-                      pl.BlockSpec(p1.shape, None),
-                      pl.BlockSpec(p2.shape, None),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(q_pilot.shape, None),
-            out_shape=jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),
-            interpret=interpret,
-        )(q_pilot, packed, w2, p1, p2, scal)
     grid = (rows // block_rows, n // block_workers)
     spec_f = pl.BlockSpec((block_rows, LANES * PACK), lambda i, k: (i, 0))
     spec_pk = pl.BlockSpec((block_workers, block_rows, LANES),
                            lambda i, k: (k, i, 0))
-    spec_w = pl.BlockSpec((block_workers, 1), lambda i, k: (k, 0))
-    out_spec = pl.BlockSpec((block_rows, LANES * PACK), lambda i, k: (i, 0))
     return pl.pallas_call(
-        functools.partial(_master_accum_kernel, block_workers=block_workers,
-                          last_k=n // block_workers - 1),
+        _master_accum_kernel,
         grid=grid,
-        in_specs=[spec_f, spec_pk, spec_w, spec_f, spec_f,
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=out_spec,
+        in_specs=[spec_f, spec_pk, SMEM, spec_f, spec_f, SMEM],
+        out_specs=spec_f,
         out_shape=jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),
         interpret=interpret,
-    )(q_pilot, packed, w2, p1, p2, scal)
+    )(q_pilot, packed, w.astype(jnp.float32), p1, p2, scal)

@@ -14,31 +14,35 @@ already masked.
 The pairwise mask and RR streams are generated INSIDE the kernel from the
 counter PRNG of ``repro.privacy.masking``: the launch consumes only the
 tiny per-pair key matrix ``(N, L)``, the antisymmetric sign matrix (with
-participation folded in) and the ``(N,)`` RR key vector — never an
-``(N, rows, 512)`` mask tensor. Each tile hashes its absolute element
-counters once (``mix32(base + local index)``) and reuses that hash across
-every pair stream of the tile (only the ``+ key`` finalizer differs per
-stream — the worker-minor batching that hides PRNG cost). At the 16-bit
-modulus one 32-bit stream word feeds two adjacent lanes, halving the
-hashing work, with the two 16-bit halves accumulated in separate planes
-and re-paired by a single shift|or + bitcast (never a per-stream lane
-shuffle). Whenever the whole cohort is resident, each unordered pair's
-stream is evaluated ONCE and ±accumulated into both endpoints —
-n(n-1)/2 stream expansions instead of n^2 — and large tiles run the
-whole pipeline as a cache-resident sweep over row chunks.
+participation folded in) and the ``(N,)`` RR key vector — all in SMEM —
+never an ``(N, rows, 512)`` mask tensor. Each tile hashes its absolute
+element counters once (``mix32(e)``, or ``mix32(e >> 1)`` at the 16-bit
+modulus, where one 32-bit stream word feeds two adjacent lanes) and reuses
+that hash across every pair stream of the tile (only the ``+ key``
+finalizer differs per stream). At 16 bits each lane takes the low or high
+half of its stream word by its parity — a lane-local shift, since the
+chip's vector unit has neither 16-bit arithmetic nor lane interleaving.
+All arithmetic runs in 32-bit lanes; words are truncated to the modulus
+only when stored. Whenever the whole cohort is resident, each unordered
+pair's stream is evaluated ONCE and ±accumulated into both endpoints —
+n(n-1)/2 stream expansions instead of n^2.
 
 ``masked_master_update_2d`` — the sum-then-unmask master. Walks the same
 2-D (rows, workers) grid as ``packed_master_update_2d``, accumulating the
-masked words into a revisited accumulator block in the WIRE dtype (native
-modular wrap — mod 2**16 or 2**32). Because the accumulation is modular,
-the pairwise masks cancel EXACTLY once all workers are folded — the master
-never observes an individual worker's ternary directions, only the sum —
-and the result is bitwise invariant under every block plan *and* every
-reduction order. The last worker step de-biases in the integer domain
-(subtract the public ``sum_k W_k`` mod the modulus), reinterprets the
-residue as the same-width SIGNED int (exact by the ``sum w_k <= 1`` +
-fixpoint-headroom bound), descales by the fixed-point multiplier (with the
-RR unbias folded in), and applies the Eq. (3) combine.
+masked words into a uint32 VMEM scratch accumulator that persists across
+the (minor) worker axis. Because the accumulation is modular (mod 2**32,
+whose low 16 bits are the mod-2**16 sum), the pairwise masks cancel EXACTLY
+once all workers are folded — the master never observes an individual
+worker's ternary directions, only the sum — and the result is bitwise
+invariant under every block plan *and* every reduction order. The last
+worker step de-biases in the integer domain (subtract the public
+``sum_k W_k``), reinterprets the residue as the wire-width SIGNED int
+(exact by the ``sum w_k <= 1`` + fixpoint-headroom bound), descales by the
+fixed-point multiplier (with the RR unbias folded in), and applies the
+Eq. (3) combine.
+
+``mask_repair_2d`` — dropout repair: regenerates the uncancelled pair
+streams of post-uplink deaths and folds them into one masked-word slab.
 
 Wire cost: one word per parameter — 8x the 2-bit plaintext wire at the
 16-bit modulus (16x at 32). The overhead is benchmarked per modulus in
@@ -51,8 +55,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_wire import _codes_any
+from repro.kernels.fused_wire import SMEM, _codes_any
 from repro.privacy import masking as pvm
 from repro.telemetry import profile as tprof
 from repro.privacy.dp import rr_fields
@@ -61,195 +66,119 @@ LANES = 128
 PACK = 4
 BLOCK_ROWS = 64
 BLOCK_WORKERS = 1
-# Rows per mask-net accumulation chunk inside one uplink tile (keeps the
-# full pair-stream working set cache-resident on CPU; a no-op for tiles
-# at or under this size).
-_NET_CHUNK_ROWS = 256
 
 
 def _tile_hash(base_u32, rows: int, width: int, word_bits: int):
     """The shared counter hash of one (rows, width) tile whose first
     element sits at absolute flat index ``base_u32`` (tiles always span
-    full rows, so the flat index is ``base + r*width + c``). At the
-    16-bit modulus the hash covers element PAIRS — half the entries,
-    expanded by ``halves16`` per stream."""
-    if word_bits == 16:
-        w2 = width // 2
-        r = jax.lax.broadcasted_iota(jnp.uint32, (rows, w2), 0)
-        c = jax.lax.broadcasted_iota(jnp.uint32, (rows, w2), 1)
-        return pvm.mix32(base_u32 // jnp.uint32(2)
-                         + r * jnp.uint32(w2) + c)
+    full rows, so the flat index is ``base + r*width + c``): ``mix32(e)``
+    per element at 32 bits, ``mix32(e >> 1)`` at 16 — adjacent lane pairs
+    share one stream word (``base`` and ``width`` are even)."""
     r = jax.lax.broadcasted_iota(jnp.uint32, (rows, width), 0)
     c = jax.lax.broadcasted_iota(jnp.uint32, (rows, width), 1)
-    return pvm.mix32(base_u32 + r * jnp.uint32(width) + c)
-
-
-def _stream_i32(key, hashed, word_bits: int):
-    """One pair stream over a tile as SIGNED values for the ± net
-    accumulation: int32 words at 32 bits (bit pattern preserved), or
-    zero-extended 16-bit values at 16 (mod-2**16 congruent either way)."""
-    vals = pvm.stream_values(key, hashed, word_bits)
+    e = base_u32 + r * jnp.uint32(width) + c
     if word_bits == 16:
-        return vals.astype(jnp.int32)
-    return jax.lax.bitcast_convert_type(vals, jnp.int32)
+        e = jax.lax.shift_right_logical(e, jnp.uint32(1))
+    return pvm.mix32(e)
+
+
+def _stream(key, hashed, word_bits: int):
+    """One stream over a tile as int32 values for the ± net accumulation:
+    the word's bit pattern at 32 bits, its low (even lane) or high (odd
+    lane) 16-bit half at 16 — mod-2**word_bits congruent either way."""
+    u = pvm.mask_stream(key, hashed)
+    if word_bits == 16:
+        lane = jax.lax.broadcasted_iota(jnp.uint32, hashed.shape, 1)
+        half = (lane & jnp.uint32(1)) * jnp.uint32(16)
+        u = jax.lax.shift_right_logical(u, half) & jnp.uint32(0xFFFF)
+    return jax.lax.bitcast_convert_type(u, jnp.int32)
+
+
+def _to_wire(acc, word_bits: int):
+    """A 32-bit accumulator tile -> the wire word (truncate mod 2**16)."""
+    if word_bits == 16:
+        return (jax.lax.bitcast_convert_type(acc, jnp.uint32)
+                & jnp.uint32(0xFFFF)).astype(jnp.uint16)
+    return jax.lax.bitcast_convert_type(acc, jnp.uint32)
+
+
+def _net_words(keys_ref, signs_ref, hashed, *, w0, bw: int, cohort: int,
+               word_bits: int, sibling: int | None = None):
+    """Net pairwise mask of each of the ``bw`` resident nodes ``w0 + j``
+    over one tile, as int32 accumulators. ``keys_ref``/``signs_ref`` are
+    the row-major flattened (cohort, cohort) key and sign matrices in
+    SMEM. When the whole cohort is resident, each unordered pair expands
+    once and ±folds into both endpoints (pairs in different ``sibling``
+    groups are structurally sign-zero and skipped statically); otherwise
+    each node folds its row of the key matrix (self/inactive pairs
+    sign-zeroed — ``w0`` is traced, so they cannot be pruned)."""
+    zeros = functools.partial(jnp.zeros, hashed.shape, jnp.int32)
+    if bw == cohort:
+        nets = [zeros() for _ in range(bw)]
+        for i in range(bw):
+            for j in range(i + 1, bw):
+                if sibling is not None and i // sibling != j // sibling:
+                    continue
+                p = i * cohort + j
+                sv = signs_ref[p] * _stream(keys_ref[p], hashed, word_bits)
+                nets[i] = nets[i] + sv
+                nets[j] = nets[j] - sv
+        return nets
+    nets = []
+    for j in range(bw):
+        row = (w0 + j) * cohort
+        acc = zeros()
+        for l in range(cohort):
+            acc = acc + signs_ref[row + l] * _stream(keys_ref[row + l],
+                                                     hashed, word_bits)
+        nets.append(acc)
+    return nets
 
 
 def _masked_pack_kernel(q_ref, p1_ref, p2_ref, beta_ref, wq_ref, keys_ref,
                         signs_ref, rrk_ref, scal_ref, out_ref, *,
                         cohort: int, word_bits: int, use_masks: bool,
-                        rr_threshold: int, gridded: bool):
+                        rr_threshold: int):
     """Masked-uplink tile: ternarize -> RR -> weight -> in-register mask
     streams -> truncate to the wire word. ``cohort`` is the total worker
     count L of the key matrix (== N in the simulator's stacked call, the
     fed size in the distributed N=1 slab call)."""
     bw, br, wide = q_ref.shape
+    base = (jnp.asarray(pl.program_id(0), jnp.uint32)
+            * jnp.uint32(br * wide))
+    w0 = pl.program_id(1) * bw
     t, alpha1 = scal_ref[0], scal_ref[1]
-    q = q_ref[...].astype(jnp.float32)
     p1 = p1_ref[...].astype(jnp.float32)
     p2 = p2_ref[...].astype(jnp.float32)
-    beta = beta_ref[...].astype(jnp.float32)[:, :, None]
-    wq = wq_ref[...][:, :, None]                       # (bw, 1, 1) uint32
-    if gridded:
-        base = (jnp.asarray(pl.program_id(0), jnp.uint32)
-                * jnp.uint32(br * wide))
-        w0 = pl.program_id(1) * bw
-    else:
-        base = jnp.uint32(0)
-        w0 = 0
-    keys = keys_ref[...]                               # (N, L) uint32
-    signs = signs_ref[...]                             # (N, L) int32
-    rrk = rrk_ref[...]                                 # (N,) uint32
-
-    def slab(base_c, qc, p1c, p2c):
-        """The full uplink pipeline over one row slab starting at absolute
-        flat element ``base_c``: ternarize -> RR -> weight -> mask ->
-        wire words (bw, rows_c, wide)."""
-        rows_c = qc.shape[1]
-        code = _codes_any(qc, p1c[None], p2c[None], t, beta, alpha1)
-        field = (code + 1.0).astype(jnp.uint32)        # exact for {0, 1, 2}
-        if rr_threshold:
-            h_rr = _tile_hash(base_c, rows_c, wide, 32)   # RR: full words
-            rr = jnp.stack([pvm.mask_stream(rrk[w0 + j], h_rr)
-                            for j in range(bw)])
-            field = rr_fields(field, rr, jnp.uint32(rr_threshold))
-        if word_bits == 16:
-            # 16-bit lane arithmetic throughout: wq < 2**fb <= 2**14 and
-            # field <= 2 keep the product exact in uint16 (and mod-2**16
-            # congruent regardless) — half-width SIMD lanes for free.
-            accc = wq.astype(jnp.uint16) * field.astype(jnp.uint16)
-        else:
-            accc = wq * field                          # mod 2**32
+    nets = None
+    if use_masks:
+        nets = _net_words(keys_ref, signs_ref,
+                          _tile_hash(base, br, wide, word_bits), w0=w0,
+                          bw=bw, cohort=cohort, word_bits=word_bits)
+    h_rr = _tile_hash(base, br, wide, 32) if rr_threshold else None
+    for j in range(bw):
+        w = w0 + j
+        code = _codes_any(q_ref[j].astype(jnp.float32), p1, p2, t,
+                          beta_ref[w], alpha1)
+        field = (code + 1.0).astype(jnp.int32).astype(jnp.uint32)
+        if rr_threshold:                               # RR: full words
+            field = rr_fields(field, pvm.mask_stream(rrk_ref[w], h_rr),
+                              jnp.uint32(rr_threshold))
+        acc = wq_ref[w] * field                        # mod 2**32
         if use_masks:
-            accc = accc + net_words(base_c, rows_c)
-        return accc
-
-    def net_words(base_c, rows_c):
-        """All resident workers' net mask words over a ``rows_c``-row
-        slab starting at absolute flat element ``base_c``, in the wire
-        dtype: (bw, rows_c, wide)."""
-        h_m = _tile_hash(base_c, rows_c, wide, word_bits)
-        if word_bits == 16:
-            # Half-width path: one 32-bit stream word covers two
-            # adjacent 16-bit lanes, but the lanes are NEVER
-            # interleaved per pair (a stride-2 shuffle per stream
-            # kills vectorization — measured 10x on XLA:CPU). The
-            # low/high halves accumulate in separate half-width
-            # planes instead.
-            nplanes, pw = 2, wide // 2
-
-            def expand(key):
-                u = pvm.mask_stream(key, h_m)
-                return ((u & jnp.uint32(0xFFFF)).astype(jnp.int32),
-                        (u >> jnp.uint32(16)).astype(jnp.int32))
-        else:
-            nplanes, pw = 1, wide
-
-            def expand(key):
-                v = pvm.mask_stream(key, h_m)
-                return (jax.lax.bitcast_convert_type(v, jnp.int32),)
-        zeros = functools.partial(jnp.zeros, (rows_c, pw), jnp.int32)
-        if bw == cohort:
-            # Whole cohort resident (any row blocking): each
-            # unordered pair expands ONCE and ±folds into both
-            # endpoints — n(n-1)/2 stream expansions instead of n^2.
-            nets = [[zeros() for _ in range(bw)]
-                    for _ in range(nplanes)]
-            for i in range(bw):
-                for j in range(i + 1, bw):
-                    s = signs[i, j]
-                    for plane, v in zip(nets, expand(keys[i, j])):
-                        sv = s * v
-                        plane[i] = plane[i] + sv
-                        plane[j] = plane[j] - sv
-        else:
-            # Grid / slab path: each resident worker folds its row
-            # of the key matrix (self/inactive pairs sign-zeroed —
-            # w0 + j is traced, the cases cannot be pruned
-            # statically).
-            nets = [[] for _ in range(nplanes)]
-            for j in range(bw):
-                w_abs = w0 + j
-                accs = [zeros() for _ in range(nplanes)]
-                for l in range(cohort):
-                    s = signs[w_abs, l]
-                    accs = [p + s * v for p, v in
-                            zip(accs, expand(keys[w_abs, l]))]
-                for plane, a in zip(nets, accs):
-                    plane.append(a)
-        if word_bits == 32:
-            return jax.lax.bitcast_convert_type(
-                jnp.stack(nets[0]), jnp.uint32)
-        # Pack the half-planes back into words with shift|or and
-        # let bitcast split uint32 -> two uint16 lanes, least-
-        # significant first — the interleaved lane order as a pure
-        # reinterpret (a stride-2 stack/reshape shuffle here
-        # measures ~5x slower on XLA:CPU). Low 16 bits of the int32
-        # accumulators are exactly the mod-2**16 residues.
-        los, his = nets
-        words = []
-        for k in range(bw):
-            lo_u = (jax.lax.bitcast_convert_type(los[k], jnp.uint32)
-                    & jnp.uint32(0xFFFF))
-            hi_u = (jax.lax.bitcast_convert_type(his[k], jnp.uint32)
-                    << jnp.uint32(16))
-            words.append(jax.lax.bitcast_convert_type(
-                lo_u | hi_u, jnp.uint16).reshape(rows_c, wide))
-        return jnp.stack(words)
-
-    # Row-chunked execution: XLA:CPU otherwise materializes every pair
-    # stream (and the codes/fields) tile-size, ~2x the masked latency in
-    # pure memory traffic at 1M params; a fori_loop over row chunks runs
-    # the whole pipeline cache-resident in one sweep. Bitwise invariant —
-    # each chunk hashes its own absolute counter range.
-    if use_masks and br > _NET_CHUNK_ROWS and br % _NET_CHUNK_ROWS == 0:
-        chunk = _NET_CHUNK_ROWS
-        wdtype = jnp.uint16 if word_bits == 16 else jnp.uint32
-
-        def fold(c, out):
-            r0 = c * chunk
-            base_c = base + (c * (chunk * wide)).astype(jnp.uint32)
-            qc = jax.lax.dynamic_slice(q, (0, r0, 0), (bw, chunk, wide))
-            p1c = jax.lax.dynamic_slice(p1, (r0, 0), (chunk, wide))
-            p2c = jax.lax.dynamic_slice(p2, (r0, 0), (chunk, wide))
-            return jax.lax.dynamic_update_slice(
-                out, slab(base_c, qc, p1c, p2c), (0, r0, 0))
-
-        out_ref[...] = jax.lax.fori_loop(
-            0, br // chunk, fold, jnp.zeros((bw, br, wide), wdtype))
-    else:
-        out_ref[...] = slab(base, q, p1, p2)
+            acc = acc + jax.lax.bitcast_convert_type(nets[j], jnp.uint32)
+        out_ref[j] = _to_wire(acc, word_bits)
 
 
 def _masked_master_kernel(q_ref, y_ref, p1_ref, p2_ref, scal_ref, sumw_ref,
-                          out_ref, acc_ref, *, block_workers: int,
-                          last_k: int, word_bits: int):
+                          out_ref, acc_ref, *, word_bits: int):
     """One (row block, worker block) step of the sum-then-unmask master.
 
-    ``acc_ref`` is the revisited accumulator output in the wire dtype (its
-    block index ignores the worker axis; the wrapper discards it): step
-    k == 0 zeroes it, every step folds its workers mod 2**word_bits, the
-    last step unmasks — integer de-bias, signed reinterpretation,
-    fixed-point descale — and writes the Eq. (3) combine into ``out_ref``.
+    ``acc_ref`` is a uint32 VMEM scratch tile that persists across the
+    (minor) worker axis: step k == 0 zeroes it, every step folds its
+    workers mod 2**32, the last step unmasks — integer de-bias, signed
+    reinterpretation at the wire width, fixed-point descale — and writes
+    the Eq. (3) combine into ``out_ref``.
     """
     k = pl.program_id(1)
 
@@ -258,16 +187,18 @@ def _masked_master_kernel(q_ref, y_ref, p1_ref, p2_ref, scal_ref, sumw_ref,
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
     acc = acc_ref[...]
-    for j in range(block_workers):        # modular: order can't change bits
-        acc = acc + y_ref[j]
+    for j in range(y_ref.shape[0]):       # modular: order can't change bits
+        acc = acc + y_ref[j].astype(jnp.uint32)
     acc_ref[...] = acc
 
-    @pl.when(k == last_k)
+    @pl.when(k == pl.num_programs(1) - 1)
     def _combine():
         t, alpha0, smult = scal_ref[0], scal_ref[1], scal_ref[2]
-        signed = jnp.int16 if word_bits == 16 else jnp.int32
         ci = jax.lax.bitcast_convert_type(acc_ref[...] - sumw_ref[0],
-                                          signed)
+                                          jnp.int32)
+        if word_bits == 16:               # sign-extend the low 16 bits
+            ci = jax.lax.shift_right_arithmetic(
+                jax.lax.shift_left(ci, jnp.int32(16)), jnp.int32(16))
         coeff = ci.astype(jnp.float32) * smult
         step = (p1_ref[...].astype(jnp.float32)
                 - p2_ref[...].astype(jnp.float32))
@@ -276,30 +207,13 @@ def _masked_master_kernel(q_ref, y_ref, p1_ref, p2_ref, scal_ref, sumw_ref,
         out_ref[...] = (q - coeff * mult).astype(out_ref.dtype)
 
 
-def _masked_master_oneshot_kernel(q_ref, y_ref, p1_ref, p2_ref, scal_ref,
-                                  sumw_ref, out_ref, *, n_workers: int,
-                                  word_bits: int):
-    """Single-step plan (the cpu-interpret optimum): same modular math."""
-    acc = jnp.zeros((q_ref.shape[0], LANES * PACK), y_ref.dtype)
-    for j in range(n_workers):
-        acc = acc + y_ref[j]
-    t, alpha0, smult = scal_ref[0], scal_ref[1], scal_ref[2]
-    signed = jnp.int16 if word_bits == 16 else jnp.int32
-    ci = jax.lax.bitcast_convert_type(acc - sumw_ref[0], signed)
-    coeff = ci.astype(jnp.float32) * smult
-    step = p1_ref[...].astype(jnp.float32) - p2_ref[...].astype(jnp.float32)
-    mult = jnp.where(t <= 1.0, alpha0, step)
-    q = q_ref[...].astype(jnp.float32)
-    out_ref[...] = (q - coeff * mult).astype(out_ref.dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("rr_threshold", "word_bits",
                                              "use_masks", "interpret",
                                              "block_rows", "block_workers"))
 def ternary_pack_masked_2d(q, p1, p2, t, beta, alpha1, wq, pair_keys,
                            pair_signs, rr_keys, *, rr_threshold: int = 0,
                            word_bits: int = 32, use_masks: bool = True,
-                           interpret: bool = True,
+                           interpret: bool,
                            block_rows: int = BLOCK_ROWS,
                            block_workers: int = BLOCK_WORKERS):
     """Masked uplink: all N workers' secure-agg wire words from ONE launch.
@@ -317,190 +231,97 @@ def ternary_pack_masked_2d(q, p1, p2, t, beta, alpha1, wq, pair_keys,
     Returns (N, R, 512) in the wire dtype (uint16 at ``word_bits=16``,
     else uint32) — already masked when it first touches HBM.
     """
-    n, rows, _ = q.shape
+    n, rows, wide = q.shape
     cohort = pair_keys.shape[1]
     out_dtype = jnp.uint16 if word_bits == 16 else jnp.uint32
     kind = "uplink_masked16" if word_bits == 16 else "uplink_masked"
-    betas = jnp.broadcast_to(
-        jnp.asarray(beta, jnp.float32).reshape(-1, 1), (n, 1))
-    wq2 = jnp.asarray(wq, jnp.uint32).reshape(n, 1)
+    betas = jnp.broadcast_to(jnp.asarray(beta, jnp.float32).reshape(-1), (n,))
     scal = jnp.stack([jnp.asarray(t, jnp.float32),
                       jnp.asarray(alpha1, jnp.float32)])
-    keys = jnp.asarray(pair_keys, jnp.uint32)
-    signs = jnp.asarray(pair_signs, jnp.int32)
-    rrk = jnp.asarray(rr_keys, jnp.uint32).reshape(n)
-    with tprof.kernel_scope(kind, rows, n, interpret):
-        return _masked_pack_call(
-            q, p1, p2, betas, wq2, keys, signs, rrk, scal, n=n, rows=rows,
-            cohort=cohort, word_bits=word_bits, use_masks=use_masks,
-            rr_threshold=rr_threshold, out_dtype=out_dtype,
-            interpret=interpret, block_rows=block_rows,
-            block_workers=block_workers)
-
-
-def _masked_pack_call(q, p1, p2, betas, wq2, keys, signs, rrk, scal, *, n,
-                      rows, cohort, word_bits, use_masks, rr_threshold,
-                      out_dtype, interpret, block_rows, block_workers):
-    wide = LANES * PACK
-    if block_rows >= rows and block_workers >= n:
-        return pl.pallas_call(
-            functools.partial(_masked_pack_kernel, cohort=cohort,
-                              word_bits=word_bits, use_masks=use_masks,
-                              rr_threshold=rr_threshold, gridded=False),
-            in_specs=[pl.BlockSpec(q.shape, None),
-                      pl.BlockSpec(p1.shape, None),
-                      pl.BlockSpec(p2.shape, None),
-                      pl.BlockSpec(betas.shape, None),
-                      pl.BlockSpec(wq2.shape, None),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((n, rows, wide), None),
-            out_shape=jax.ShapeDtypeStruct((n, rows, wide), out_dtype),
-            interpret=interpret,
-        )(q, p1, p2, betas, wq2, keys, signs, rrk, scal)
-    grid = (rows // block_rows, n // block_workers)
+    keys = jnp.asarray(pair_keys, jnp.uint32).reshape(-1)
+    signs = jnp.asarray(pair_signs, jnp.int32).reshape(-1)
     q_spec = pl.BlockSpec((block_workers, block_rows, wide),
                           lambda i, k: (k, i, 0))
     h_spec = pl.BlockSpec((block_rows, wide), lambda i, k: (i, 0))
-    w_spec = pl.BlockSpec((block_workers, 1), lambda i, k: (k, 0))
-    return pl.pallas_call(
-        functools.partial(_masked_pack_kernel, cohort=cohort,
-                          word_bits=word_bits, use_masks=use_masks,
-                          rr_threshold=rr_threshold, gridded=True),
-        grid=grid,
-        in_specs=[q_spec, h_spec, h_spec, w_spec, w_spec,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((n, rows, wide), out_dtype),
-        interpret=interpret,
-    )(q, p1, p2, betas, wq2, keys, signs, rrk, scal)
+    with tprof.kernel_scope(kind, rows, n, interpret):
+        return pl.pallas_call(
+            functools.partial(_masked_pack_kernel, cohort=cohort,
+                              word_bits=word_bits, use_masks=use_masks,
+                              rr_threshold=rr_threshold),
+            grid=(rows // block_rows, n // block_workers),
+            in_specs=[q_spec, h_spec, h_spec] + [SMEM] * 6,
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((n, rows, wide), out_dtype),
+            interpret=interpret,
+        )(q, p1, p2, betas, jnp.asarray(wq, jnp.uint32).reshape(n), keys,
+          signs, jnp.asarray(rr_keys, jnp.uint32).reshape(n), scal)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows",
                                              "block_workers"))
 def masked_master_update_2d(q_pilot, masked, sum_wq, p1, p2, t, alpha0,
-                            scale_mult, *, interpret: bool = True,
+                            scale_mult, *, interpret: bool,
                             block_rows: int = BLOCK_ROWS,
                             block_workers: int = BLOCK_WORKERS):
     """Sum-then-unmask Eq. (3) over masked wire words.
 
     q_pilot/p1/p2 (R, 512) float; masked (N, R, 512) uint16 or uint32 (the
     wire dtype picks the modulus); ``sum_wq`` the public scalar
-    ``sum_k W_k`` (uint32 — truncated to the modulus here); ``scale_mult``
-    the fixed-point descale with the RR unbias folded in; ``t`` may be
-    traced. Returns (R, 512) in q_pilot.dtype. Bitwise invariant under
-    every (block_rows, block_workers) plan — modular accumulation is
-    order-free.
+    ``sum_k W_k`` (uint32 — only its residue mod the modulus matters);
+    ``scale_mult`` the fixed-point descale with the RR unbias folded in;
+    ``t`` may be traced. Returns (R, 512) in q_pilot.dtype. Bitwise
+    invariant under every (block_rows, block_workers) plan — modular
+    accumulation is order-free.
     """
-    n, rows, _ = masked.shape
+    n, rows, wide = masked.shape
     word_bits = 16 if masked.dtype == jnp.uint16 else 32
     scal = jnp.stack([jnp.asarray(t, jnp.float32),
                       jnp.asarray(alpha0, jnp.float32),
                       jnp.asarray(scale_mult, jnp.float32)])
-    sumw = jnp.asarray(sum_wq, jnp.uint32)
-    if word_bits == 16:
-        sumw = (sumw & jnp.uint32(0xFFFF)).astype(jnp.uint16)
-    sumw = sumw.reshape(1)
+    sumw = jnp.asarray(sum_wq, jnp.uint32).reshape(1)
     kind = "master_masked16" if word_bits == 16 else "master_masked"
+    spec_f = pl.BlockSpec((block_rows, wide), lambda i, k: (i, 0))
+    spec_y = pl.BlockSpec((block_workers, block_rows, wide),
+                          lambda i, k: (k, i, 0))
     with tprof.kernel_scope(kind, rows, n, interpret):
-        if block_rows >= rows and block_workers >= n:
-            return pl.pallas_call(
-                functools.partial(_masked_master_oneshot_kernel, n_workers=n,
-                                  word_bits=word_bits),
-                in_specs=[pl.BlockSpec(q_pilot.shape, None),
-                          pl.BlockSpec(masked.shape, None),
-                          pl.BlockSpec(p1.shape, None),
-                          pl.BlockSpec(p2.shape, None),
-                          pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec(q_pilot.shape, None),
-                out_shape=jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),
-                interpret=interpret,
-            )(q_pilot, masked, p1, p2, scal, sumw)
-        grid = (rows // block_rows, n // block_workers)
-        spec_f = pl.BlockSpec((block_rows, LANES * PACK), lambda i, k: (i, 0))
-        spec_y = pl.BlockSpec((block_workers, block_rows, LANES * PACK),
-                              lambda i, k: (k, i, 0))
-        out, _acc = pl.pallas_call(
-            functools.partial(_masked_master_kernel,
-                              block_workers=block_workers,
-                              last_k=n // block_workers - 1,
-                              word_bits=word_bits),
-            grid=grid,
-            in_specs=[spec_f, spec_y, spec_f, spec_f,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[spec_f, spec_f],
-            out_shape=[jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),
-                       jax.ShapeDtypeStruct(q_pilot.shape, masked.dtype)],
+        return pl.pallas_call(
+            functools.partial(_masked_master_kernel, word_bits=word_bits),
+            grid=(rows // block_rows, n // block_workers),
+            in_specs=[spec_f, spec_y, spec_f, spec_f, SMEM, SMEM],
+            out_specs=spec_f,
+            out_shape=jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),
+            scratch_shapes=[pltpu.VMEM((block_rows, wide), jnp.uint32)],
             interpret=interpret,
         )(q_pilot, masked, p1, p2, scal, sumw)
-        return out
 
 
 def _mask_repair_kernel(y_ref, keys_ref, coeff_ref, out_ref, *,
-                        n_pairs: int, word_bits: int, gridded: bool):
+                        n_pairs: int, word_bits: int):
     """Dropout-repair tile: fold ``coeff[p] * stream(keys[p])`` for every
     repair pair into a (rows, wide) slab of masked wire words, mod
-    2**word_bits. The accumulation planes START from the slab's own words,
-    so the repaired output is one pass — no separate residue tensor.
+    2**word_bits. The accumulation STARTS from the slab's own words, so the
+    repaired output is one pass — no separate residue tensor.
     Zero-coefficient pairs (the common case: coeff is nonzero only for
     dead-live pairs) skip their stream expansion via ``lax.cond``."""
     br, wide = y_ref.shape
     base = (jnp.asarray(pl.program_id(0), jnp.uint32)
-            * jnp.uint32(br * wide) if gridded else jnp.uint32(0))
-    keys = keys_ref[...]                                   # (P,) uint32
-    coeff = coeff_ref[...]                                 # (P,) int32
+            * jnp.uint32(br * wide))
     h = _tile_hash(base, br, wide, word_bits)
-    y = y_ref[...]
-    if word_bits == 16:
-        # Same half-plane layout as the uplink: reinterpret uint16 lane
-        # pairs as uint32 words (low lane first), accumulate lo/hi in
-        # separate int32 planes, repack with shift|or at the end.
-        pw = wide // 2
-        w0 = jax.lax.bitcast_convert_type(y.reshape(br, pw, 2), jnp.uint32)
-        planes0 = ((w0 & jnp.uint32(0xFFFF)).astype(jnp.int32),
-                   (w0 >> jnp.uint32(16)).astype(jnp.int32))
+    acc0 = jax.lax.bitcast_convert_type(y_ref[...].astype(jnp.uint32),
+                                        jnp.int32)
 
-        def expand(key):
-            u = pvm.mask_stream(key, h)
-            return ((u & jnp.uint32(0xFFFF)).astype(jnp.int32),
-                    (u >> jnp.uint32(16)).astype(jnp.int32))
-    else:
-        planes0 = (jax.lax.bitcast_convert_type(y, jnp.int32),)
-
-        def expand(key):
-            return (jax.lax.bitcast_convert_type(
-                pvm.mask_stream(key, h), jnp.int32),)
-
-    def fold(p, planes):
-        c = coeff[p]
+    def fold(p, acc):
+        c = coeff_ref[p]
         return jax.lax.cond(
-            c == 0, lambda ps: ps,
-            lambda ps: tuple(a + c * v
-                             for a, v in zip(ps, expand(keys[p]))),
-            planes)
+            c == 0, lambda a: a,
+            lambda a: a + c * _stream(keys_ref[p], h, word_bits), acc)
 
-    planes = jax.lax.fori_loop(0, n_pairs, fold, planes0)
-    if word_bits == 16:
-        lo, hi = planes
-        lo_u = (jax.lax.bitcast_convert_type(lo, jnp.uint32)
-                & jnp.uint32(0xFFFF))
-        hi_u = (jax.lax.bitcast_convert_type(hi, jnp.uint32)
-                << jnp.uint32(16))
-        out_ref[...] = jax.lax.bitcast_convert_type(
-            lo_u | hi_u, jnp.uint16).reshape(br, wide)
-    else:
-        out_ref[...] = jax.lax.bitcast_convert_type(planes[0], jnp.uint32)
+    out_ref[...] = _to_wire(jax.lax.fori_loop(0, n_pairs, fold, acc0),
+                            word_bits)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def mask_repair_2d(y, pair_keys, pair_coeff, *, interpret: bool = True,
+def mask_repair_2d(y, pair_keys, pair_coeff, *, interpret: bool,
                    block_rows: int = BLOCK_ROWS):
     """Repair a masked-word slab after post-uplink deaths, in one launch.
 
@@ -519,30 +340,16 @@ def mask_repair_2d(y, pair_keys, pair_coeff, *, interpret: bool = True,
     if n_pairs == 0:
         return y
     word_bits = 16 if y.dtype == jnp.uint16 else 32
-    keys = jnp.asarray(pair_keys, jnp.uint32)
-    coeff = jnp.asarray(pair_coeff, jnp.int32)
-    kern = functools.partial(_mask_repair_kernel, n_pairs=n_pairs,
-                             word_bits=word_bits)
     kind = "mask_repair16" if word_bits == 16 else "mask_repair"
+    spec = pl.BlockSpec((block_rows, wide), lambda i: (i, 0))
     with tprof.kernel_scope(kind, rows, 1, interpret):
-        if block_rows >= rows:
-            return pl.pallas_call(
-                functools.partial(kern, gridded=False),
-                in_specs=[pl.BlockSpec(y.shape, None),
-                          pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec(y.shape, None),
-                out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-                interpret=interpret,
-            )(y, keys, coeff)
-        spec = pl.BlockSpec((block_rows, wide), lambda i: (i, 0))
         return pl.pallas_call(
-            functools.partial(kern, gridded=True),
+            functools.partial(_mask_repair_kernel, n_pairs=n_pairs,
+                              word_bits=word_bits),
             grid=(rows // block_rows,),
-            in_specs=[spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[spec, SMEM, SMEM],
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
             interpret=interpret,
-        )(y, keys, coeff)
+        )(y, jnp.asarray(pair_keys, jnp.uint32),
+          jnp.asarray(pair_coeff, jnp.int32))

@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 256
@@ -24,15 +25,15 @@ BLOCK_ROWS = 256
 
 def _kernel(q_ref, t_ref, w_ref, p1_ref, p2_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)            # (R, 128)
-    tern = t_ref[...].astype(jnp.float32)         # (N, R, 128)
-    w = w_ref[...].astype(jnp.float32)            # (N,)
-    coeff = jnp.tensordot(w, tern, axes=1)        # (R, 128)
+    coeff = jnp.zeros(q.shape, jnp.float32)       # Σ_k w_k T_k, w in SMEM
+    for k in range(t_ref.shape[0]):
+        coeff = coeff + w_ref[k] * t_ref[k].astype(jnp.float32)
     step = p1_ref[...].astype(jnp.float32) - p2_ref[...].astype(jnp.float32)
     out_ref[...] = (q - coeff * step).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def master_update_2d(q_pilot, tern, w, p1, p2, *, interpret: bool = True,
+def master_update_2d(q_pilot, tern, w, p1, p2, *, interpret: bool,
                      block_rows: int = BLOCK_ROWS):
     """q_pilot/p1/p2 (R, 128); tern (N, R, 128) int8; w (N,) fp32 (masked).
 
@@ -45,7 +46,7 @@ def master_update_2d(q_pilot, tern, w, p1, p2, *, interpret: bool = True,
     return pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[spec2d, spec3d, pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[spec2d, spec3d, pl.BlockSpec(memory_space=pltpu.SMEM),
                   spec2d, spec2d],
         out_specs=spec2d,
         out_shape=jax.ShapeDtypeStruct(q_pilot.shape, q_pilot.dtype),

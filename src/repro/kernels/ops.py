@@ -1,7 +1,8 @@
 """Public jit'd wrappers over the Pallas kernels.
 
 Handles: flat (or pytree) → padded (rows, 128) layout, interpret-mode
-selection (Python execution on CPU, compiled on TPU), block-plan selection
+selection (``tune.use_interpreter``: the Pallas interpreter on the CPU
+platform only, compiled Mosaic kernels everywhere else), block-plan selection
 (callers that leave ``block_rows``/``block_workers`` unset get the
 ``repro.kernels.tune`` plan for their shape and backend), and un-padding.
 These are drop-in replacements for the core/* reference functions and are
@@ -23,10 +24,6 @@ from repro.telemetry import profile as tprof
 from repro.utils import round_up
 
 LANES = 128
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_2d(x: jax.Array, row_multiple: int, lane_multiple: int = LANES):
@@ -59,7 +56,7 @@ def _stacked_plan(kind: str, rows: int, n: int, block_rows: int | None,
 
 def ternary_encode(q, p1, p2, beta: float, interpret: bool | None = None):
     """Eq. (5) over an arbitrary-shape array; returns int8 of q.shape."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     q2, n = _to_2d(q, 8)
     p12, _ = _to_2d(p1, 8)
     p22, _ = _to_2d(p2, 8)
@@ -70,7 +67,7 @@ def ternary_encode(q, p1, p2, beta: float, interpret: bool | None = None):
 
 
 def ternary_encode_round1(q, p0, alpha: float, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     q2, n = _to_2d(q, 8)
     p02, _ = _to_2d(p0, 8)
     br = _block_rows_for(q2.shape[0], te.BLOCK_ROWS)
@@ -81,7 +78,7 @@ def ternary_encode_round1(q, p0, alpha: float, interpret: bool | None = None):
 
 def pack2bit(t, interpret: bool | None = None):
     """int8 codes any shape → uint8 (ceil(n/4),) flat packed buffer."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     t2, n = _to_2d(t, 8, LANES * pk.PACK)
     br = _block_rows_for(t2.shape[0], pk.BLOCK_ROWS)
     out = pk.pack2bit_2d(t2, interpret=interpret, block_rows=br)
@@ -91,11 +88,23 @@ def pack2bit(t, interpret: bool | None = None):
 
 def unpack2bit(b, n: int, interpret: bool | None = None):
     """uint8 packed buffer → int8 (n,) codes."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     b2, nb = _to_2d(b, 8, LANES)
     br = _block_rows_for(b2.shape[0], pk.BLOCK_ROWS)
     out = pk.unpack2bit_2d(b2, interpret=interpret, block_rows=br)
     return out.reshape(-1)[:n]
+
+
+def _uplink_one(q4, p14, p24, t, beta, alpha1, *, interpret: bool,
+                block_rows: int | None):
+    """One worker's uplink over (R, 512) views: the stacked kernel at N=1,
+    under the ``uplink`` plan kind."""
+    r4 = q4.shape[0]
+    br, _ = _stacked_plan("uplink", r4, 1, block_rows, 1, interpret)
+    with tprof.kernel_scope("uplink", r4, 1, interpret):
+        return fw.ternary_pack_stacked_2d(
+            q4[None], p14, p24, t, beta, alpha1, interpret=interpret,
+            block_rows=br, block_workers=1)[0]
 
 
 def ternary_pack(q, p1, p2, beta: float, interpret: bool | None = None):
@@ -104,29 +113,23 @@ def ternary_pack(q, p1, p2, beta: float, interpret: bool | None = None):
     Equals ``pack2bit(ternary_encode(q, p1, p2, beta))`` in one launch with
     no int8 intermediate. Returns uint8 (ceil(n/4),) packed wire bytes.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     q2, n = _to_2d(q, 8, LANES * fw.PACK)
     p12, _ = _to_2d(p1, 8, LANES * fw.PACK)
     p22, _ = _to_2d(p2, 8, LANES * fw.PACK)
-    br = _block_rows_for(
-        q2.shape[0], tune.lookup("uplink", q2.shape[0],
-                                 interpret=interpret)[0])
-    out = fw.ternary_pack_2d(q2, p12, p22, beta, interpret=interpret,
-                             block_rows=br)
+    out = _uplink_one(q2, p12, p22, 2, beta, 0.0, interpret=interpret,
+                      block_rows=None)
     n_bytes = -(-n // fw.PACK)
     return out.reshape(-1)[:n_bytes]
 
 
 def ternary_pack_round1(q, p0, alpha: float, interpret: bool | None = None):
     """Round-1 (Eq. (4)) variant of :func:`ternary_pack`."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     q2, n = _to_2d(q, 8, LANES * fw.PACK)
     p02, _ = _to_2d(p0, 8, LANES * fw.PACK)
-    br = _block_rows_for(
-        q2.shape[0], tune.lookup("uplink", q2.shape[0],
-                                 interpret=interpret)[0])
-    out = fw.ternary_pack_round1_2d(q2, p02, alpha, interpret=interpret,
-                                    block_rows=br)
+    out = _uplink_one(q2, p02, p02, 1, 0.0, alpha, interpret=interpret,
+                      block_rows=None)
     n_bytes = -(-n // fw.PACK)
     return out.reshape(-1)[:n_bytes]
 
@@ -136,49 +139,19 @@ def flat_ternary_pack(buf_q, buf_p1, buf_p2, *, t: int, beta: float,
                       block_rows: int | None = None):
     """Fused uplink over FlatParams buffers: (rows, 128) → (rows//4, 128).
 
-    ``t`` is the (static) 1-based round index: round 1 uses the Eq. (4)
-    threshold ``alpha1`` against ``buf_p1`` (= P^0), later rounds Eq. (5)
-    with ``beta`` against the (P^{t-1}, P^{t-2}) history.
+    ``t`` is the 1-based round index: round 1 uses the Eq. (4) threshold
+    ``alpha1`` against ``buf_p1`` (= P^0), later rounds Eq. (5) with
+    ``beta`` against the (P^{t-1}, P^{t-2}) history. ``t`` and ``beta``
+    may be traced (the branch is selected in-register), so this also
+    serves jit'd round loops such as the distributed sync body — e.g. with
+    this fed instance's own beta_k gathered from a per-worker vector.
     """
-    interpret = _default_interpret() if interpret is None else interpret
-    rows = buf_q.shape[0]
-    r4 = rows // fw.PACK
-    q4 = buf_q.reshape(r4, LANES * fw.PACK)
-    br = _block_rows_for(
-        r4, block_rows or tune.lookup("uplink", r4, interpret=interpret)[0])
-    with tprof.kernel_scope("uplink", r4, 1, interpret):
-        if t <= 1:
-            return fw.ternary_pack_round1_2d(
-                q4, buf_p1.reshape(r4, LANES * fw.PACK), alpha1,
-                interpret=interpret, block_rows=br)
-        return fw.ternary_pack_2d(
-            q4, buf_p1.reshape(r4, LANES * fw.PACK),
-            buf_p2.reshape(r4, LANES * fw.PACK), beta,
-            interpret=interpret, block_rows=br)
-
-
-def flat_ternary_pack_traced(buf_q, buf_p1, buf_p2, *, t, beta,
-                             alpha1: float, interpret: bool | None = None,
-                             block_rows: int | None = None):
-    """Fused uplink over FlatParams buffers with a *traced* round index.
-
-    Same contract as :func:`flat_ternary_pack` but ``t`` may be a traced
-    scalar (the Eq. (4)/(5) branch is selected in-register), so it can live
-    inside a jit'd round loop such as the distributed sync body. ``beta``
-    may also be traced — e.g. this fed instance's own beta_k gathered from a
-    heterogeneous per-worker vector.
-    """
-    interpret = _default_interpret() if interpret is None else interpret
-    rows = buf_q.shape[0]
-    r4 = rows // fw.PACK
+    interpret = tune.use_interpreter(interpret)
+    r4 = buf_q.shape[0] // fw.PACK
     wide = LANES * fw.PACK
-    br = _block_rows_for(
-        r4, block_rows or tune.lookup("uplink", r4, interpret=interpret)[0])
-    with tprof.kernel_scope("uplink", r4, 1, interpret):
-        return fw.ternary_pack_any_2d(
-            buf_q.reshape(r4, wide), buf_p1.reshape(r4, wide),
-            buf_p2.reshape(r4, wide), t, beta, alpha1,
-            interpret=interpret, block_rows=br)
+    return _uplink_one(buf_q.reshape(r4, wide), buf_p1.reshape(r4, wide),
+                       buf_p2.reshape(r4, wide), t, beta, alpha1,
+                       interpret=interpret, block_rows=block_rows)
 
 
 def flat_ternary_pack_stacked(bufs_q, buf_p1, buf_p2, *, t, beta,
@@ -195,7 +168,7 @@ def flat_ternary_pack_stacked(bufs_q, buf_p1, buf_p2, *, t, beta,
     beta_k. ``block_rows``/``block_workers`` default to the tuned plan for
     (rows, N, backend) — see ``repro.kernels.tune``.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     n, rows, _ = bufs_q.shape
     r4 = rows // fw.PACK
     wide = LANES * fw.PACK
@@ -225,7 +198,7 @@ def flat_master_update(buf_q_pilot, packed_stacked, w, buf_p1, buf_p2, *,
     ``ref.packed_master_accum_ref``). Block sizes default to the tuned
     plan for (rows, N, backend).
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     rows = buf_q_pilot.shape[0]
     n = packed_stacked.shape[0]
     r4 = rows // fw.PACK
@@ -265,7 +238,7 @@ def flat_ternary_pack_masked(bufs_q, buf_p1, buf_p2, *, t, beta,
     ``uplink_masked`` by modulus, chaining down to the ``uplink_stacked``
     plan when untuned) — every plan produces identical bits.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     n, rows, _ = bufs_q.shape
     r4 = rows // fw.PACK
     wide = LANES * fw.PACK
@@ -296,7 +269,7 @@ def flat_masked_master_update(buf_q_pilot, masked, sum_wq, buf_p1, buf_p2,
     every block plan (modular accumulation is order-free; the oracle is
     ``repro.privacy.ref.masked_master_ref``).
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     rows = buf_q_pilot.shape[0]
     n = masked.shape[0]
     r4 = rows // fw.PACK
@@ -329,7 +302,7 @@ def flat_mask_repair(words, pair_keys, pair_coeff, *,
     chain down to the ``uplink`` row plan when untuned; every plan
     produces identical bits (modular addition is order-free).
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     r4 = words.shape[0]
     kind = "mask_repair16" if words.dtype == jnp.uint16 else "mask_repair"
     tuned_br, _ = tune.lookup(kind, r4, 1, interpret=interpret)
@@ -353,7 +326,7 @@ def flat_partial_sum(packed, wq, *, fanout: int, word_bits: int = 32,
     kind ``partial_sum`` keyed by (rows, fanout, backend); every plan
     produces identical bits (modular accumulation is order-free).
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     c, r4, _ = packed.shape
     g = -(-c // fanout)
     # Plans are keyed by fanout (the per-node working set); the group-axis
@@ -388,7 +361,7 @@ def flat_masked_partial_sum(words, keys, signs, *, fanout: int,
     (by dtype) keyed by (rows, fanout, backend), chaining down to the
     plain ``partial_sum`` plan when untuned.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     c, r4, _ = words.shape
     g = -(-c // fanout)
     kind = ("partial_sum_masked16" if words.dtype == jnp.uint16
@@ -412,7 +385,7 @@ def master_update(q_pilot, tern_stacked, w, p1, p2,
 
     Returns array of q_pilot.shape/dtype.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = tune.use_interpreter(interpret)
     n_workers = tern_stacked.shape[0]
     q2, n = _to_2d(q_pilot, 8)
     p12, _ = _to_2d(p1, 8)

@@ -44,7 +44,7 @@ def _unpack_kernel(b_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def pack2bit_2d(t, *, interpret: bool = True, block_rows: int = BLOCK_ROWS):
+def pack2bit_2d(t, *, interpret: bool, block_rows: int = BLOCK_ROWS):
     """t int8 (R, 512), R % block_rows == 0 → uint8 (R, 128).
 
     Group layout matches ref.pack2bit_ref: four consecutive codes → 1 byte.
@@ -62,7 +62,7 @@ def pack2bit_2d(t, *, interpret: bool = True, block_rows: int = BLOCK_ROWS):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def unpack2bit_2d(b, *, interpret: bool = True, block_rows: int = BLOCK_ROWS):
+def unpack2bit_2d(b, *, interpret: bool, block_rows: int = BLOCK_ROWS):
     """b uint8 (R, 128) → int8 (R, 512)."""
     rows = b.shape[0]
     grid = (rows // block_rows,)

@@ -19,7 +19,8 @@ plain integer) word partials: sums each sibling group's children mod
 2**word_bits and adds the EMITTING node's own net pairwise mask,
 regenerated in-register from the level's (G, G) counter-key matrix (the
 ``masked_wire`` stream idiom — shared tile hash, pair dedup whenever the
-whole level is resident, half-width lo/hi planes at the 16-bit modulus).
+whole level is resident, 32-bit lanes truncated to the wire word on
+store).
 The children's masks — scoped to exactly this sibling group by
 ``masking.tree_pair_signs`` — cancel inside the group sum; the node's own
 mask keeps the partial masked while it crosses the next tree edge, and
@@ -34,8 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.masked_wire import _tile_hash
-from repro.privacy import masking as pvm
+from repro.kernels.fused_wire import SMEM, _fields
+from repro.kernels.masked_wire import _net_words, _tile_hash, _to_wire
 from repro.telemetry import profile as tprof
 
 LANES = 128
@@ -44,46 +45,35 @@ BLOCK_ROWS = 64
 BLOCK_GROUPS = 1
 
 
-def _weighted_fields(b, w, br: int, word_bits: int):
-    """One child's packed (br, 128) uint8 codes -> (br, 512) fixed-point-
-    weighted fields ``W_c * field`` in the wire word dtype. The register
+def _weighted_fields(b, w):
+    """One child's packed (br, 128) uint8 codes -> (br, 512) uint32
+    fixed-point-weighted fields ``W_c * field`` (mod 2**32). The register
     2-bit decode of ``fused_wire._weighted_decode`` minus the de-bias:
     fields stay biased {0, 1, 2} so the ΣW_k subtraction can happen once,
-    at the root. At the 16-bit modulus the product runs in uint16 lanes
-    (W < 2**14, field <= 2 — exact, and mod-2**16 congruent regardless)."""
-    bi = b.astype(jnp.int32)[:, :, None]
-    e = jax.lax.broadcasted_iota(jnp.int32, (1, 1, PACK), 2)
-    f = (bi // jax.lax.shift_left(jnp.int32(1), 2 * e)) % 4
-    f = f.reshape(br, LANES * PACK).astype(jnp.uint32)
-    if word_bits == 16:
-        return w.astype(jnp.uint16) * f.astype(jnp.uint16)
-    return w * f
+    at the root."""
+    return w * _fields(b).astype(jnp.uint32)
 
 
 def _partial_sum_kernel(pk_ref, wq_ref, out_ref, *, fanout: int,
                         word_bits: int):
     """One (row block, group block) tile: each resident sibling group's
-    packed children decode + weight + modular sum. The same body serves
-    the one-shot plan (whole operands, no grid) and the gridded plan —
-    nothing here depends on absolute position."""
-    cb, br, _ = pk_ref.shape
-    bg = cb // fanout
-    wide = LANES * PACK
-    acc_dtype = jnp.uint16 if word_bits == 16 else jnp.uint32
-    outs = []
-    for k in range(bg):
-        acc = jnp.zeros((br, wide), acc_dtype)
+    packed children decode + weight + modular sum (32-bit lanes, truncated
+    to the wire word on store). Children's weights come from SMEM at the
+    grid's group index."""
+    cb = pk_ref.shape[0]
+    c0 = pl.program_id(1) * cb
+    for k in range(cb // fanout):
+        acc = None
         for j in range(fanout):
             c = k * fanout + j
-            acc = acc + _weighted_fields(pk_ref[c], wq_ref[c, 0], br,
-                                         word_bits)
-        outs.append(acc)
-    out_ref[...] = jnp.stack(outs)
+            wf = _weighted_fields(pk_ref[c], wq_ref[c0 + c])
+            acc = wf if acc is None else acc + wf
+        out_ref[k] = _to_wire(acc, word_bits)
 
 
 def _masked_partial_kernel(y_ref, keys_ref, signs_ref, out_ref, *,
                            fanout: int, word_bits: int, use_masks: bool,
-                           sibling: int, gridded: bool):
+                           sibling: int, g_total: int):
     """One tile of the interior sub-aggregate: sum each sibling group's
     children words mod 2**word_bits, then add each emitting node's net
     mask from the level's counter keys (``use_masks=False`` — the plain
@@ -91,93 +81,30 @@ def _masked_partial_kernel(y_ref, keys_ref, signs_ref, out_ref, *,
     entirely)."""
     cb, br, wide = y_ref.shape
     bg = cb // fanout
-    g_total = keys_ref.shape[0]
-    if gridded:
-        base = (jnp.asarray(pl.program_id(0), jnp.uint32)
-                * jnp.uint32(br * wide))
-        g0 = pl.program_id(1) * bg
-    else:
-        base = jnp.uint32(0)
-        g0 = 0
     sums = []
     for k in range(bg):
-        acc = y_ref[k * fanout]
+        acc = y_ref[k * fanout].astype(jnp.uint32)
         for j in range(1, fanout):        # modular: order can't change bits
-            acc = acc + y_ref[k * fanout + j]
+            acc = acc + y_ref[k * fanout + j].astype(jnp.uint32)
         sums.append(acc)
-    out = jnp.stack(sums)
-    if not use_masks or g_total < 2:
-        out_ref[...] = out
-        return
-    keys = keys_ref[...]                               # (G, G) uint32
-    signs = signs_ref[...]                             # (G, G) int32
-    h_m = _tile_hash(base, br, wide, word_bits)
-    if word_bits == 16:
-        # Half-width lo/hi planes, repacked once by shift|or + bitcast —
-        # the masked_wire layout, so the jnp net_masks oracle matches
-        # bitwise.
-        nplanes, pw = 2, wide // 2
-
-        def expand(key):
-            u = pvm.mask_stream(key, h_m)
-            return ((u & jnp.uint32(0xFFFF)).astype(jnp.int32),
-                    (u >> jnp.uint32(16)).astype(jnp.int32))
-    else:
-        nplanes, pw = 1, wide
-
-        def expand(key):
-            v = pvm.mask_stream(key, h_m)
-            return (jax.lax.bitcast_convert_type(v, jnp.int32),)
-    zeros = functools.partial(jnp.zeros, (br, pw), jnp.int32)
-    if bg == g_total:
-        # Whole level resident: each unordered sibling pair's stream
-        # expands ONCE and ±folds into both endpoints. Cross-group pairs
-        # are structurally sign-zero (tree_pair_signs), so they are
-        # skipped statically — sibling groups, not G(G-1)/2 pairs.
-        nets = [[zeros() for _ in range(bg)] for _ in range(nplanes)]
-        for i in range(bg):
-            for j in range(i + 1, bg):
-                if i // sibling != j // sibling:
-                    continue
-                s = signs[i, j]
-                for plane, v in zip(nets, expand(keys[i, j])):
-                    sv = s * v
-                    plane[i] = plane[i] + sv
-                    plane[j] = plane[j] - sv
-    else:
-        # Gridded group blocks: each resident node folds its key row
-        # (cross-group/inactive pairs sign-zeroed — g0 + k is traced).
-        nets = [[] for _ in range(nplanes)]
-        for k in range(bg):
-            accs = [zeros() for _ in range(nplanes)]
-            for l in range(g_total):
-                s = signs[g0 + k, l]
-                accs = [p + s * v
-                        for p, v in zip(accs, expand(keys[g0 + k, l]))]
-            for plane, a in zip(nets, accs):
-                plane.append(a)
-    if word_bits == 32:
-        net_words = jax.lax.bitcast_convert_type(jnp.stack(nets[0]),
-                                                 jnp.uint32)
-    else:
-        los, his = nets
-        words = []
-        for k in range(bg):
-            lo_u = (jax.lax.bitcast_convert_type(los[k], jnp.uint32)
-                    & jnp.uint32(0xFFFF))
-            hi_u = (jax.lax.bitcast_convert_type(his[k], jnp.uint32)
-                    << jnp.uint32(16))
-            words.append(jax.lax.bitcast_convert_type(
-                lo_u | hi_u, jnp.uint16).reshape(br, wide))
-        net_words = jnp.stack(words)
-    out_ref[...] = out + net_words
+    if use_masks and g_total >= 2:
+        base = (jnp.asarray(pl.program_id(0), jnp.uint32)
+                * jnp.uint32(br * wide))
+        nets = _net_words(keys_ref, signs_ref,
+                          _tile_hash(base, br, wide, word_bits),
+                          w0=pl.program_id(1) * bg, bw=bg, cohort=g_total,
+                          word_bits=word_bits, sibling=sibling)
+        sums = [s + jax.lax.bitcast_convert_type(m, jnp.uint32)
+                for s, m in zip(sums, nets)]
+    for k in range(bg):
+        out_ref[k] = _to_wire(sums[k], word_bits)
 
 
 @functools.partial(jax.jit, static_argnames=("fanout", "word_bits",
                                              "interpret", "block_rows",
                                              "block_groups"))
 def partial_sum_2d(packed, wq, *, fanout: int, word_bits: int = 32,
-                   interpret: bool = True, block_rows: int = BLOCK_ROWS,
+                   interpret: bool, block_rows: int = BLOCK_ROWS,
                    block_groups: int = BLOCK_GROUPS):
     """Leaf-level sub-aggregate: (C, R, 128) packed uint8 + (C,) public
     fixed-point weights -> (C/fanout, R, 512) word partials, one launch.
@@ -195,39 +122,27 @@ def partial_sum_2d(packed, wq, *, fanout: int, word_bits: int = 32,
     g = c // fanout
     wide = LANES * PACK
     out_dtype = jnp.uint16 if word_bits == 16 else jnp.uint32
-    wq2 = jnp.asarray(wq, jnp.uint32).reshape(c, 1)
-    kern = functools.partial(_partial_sum_kernel, fanout=fanout,
-                             word_bits=word_bits)
+    pk_spec = pl.BlockSpec((block_groups * fanout, block_rows, LANES),
+                           lambda i, k: (k, i, 0))
+    out_spec = pl.BlockSpec((block_groups, block_rows, wide),
+                            lambda i, k: (k, i, 0))
     with tprof.kernel_scope("partial_sum", rows, fanout, interpret):
-        if block_rows >= rows and block_groups >= g:
-            return pl.pallas_call(
-                kern,
-                in_specs=[pl.BlockSpec(packed.shape, None),
-                          pl.BlockSpec(wq2.shape, None)],
-                out_specs=pl.BlockSpec((g, rows, wide), None),
-                out_shape=jax.ShapeDtypeStruct((g, rows, wide), out_dtype),
-                interpret=interpret,
-            )(packed, wq2)
-        grid = (rows // block_rows, g // block_groups)
-        pk_spec = pl.BlockSpec((block_groups * fanout, block_rows, LANES),
-                               lambda i, k: (k, i, 0))
-        wq_spec = pl.BlockSpec((block_groups * fanout, 1), lambda i, k: (k, 0))
-        out_spec = pl.BlockSpec((block_groups, block_rows, wide),
-                                lambda i, k: (k, i, 0))
         return pl.pallas_call(
-            kern, grid=grid,
-            in_specs=[pk_spec, wq_spec],
+            functools.partial(_partial_sum_kernel, fanout=fanout,
+                              word_bits=word_bits),
+            grid=(rows // block_rows, g // block_groups),
+            in_specs=[pk_spec, SMEM],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((g, rows, wide), out_dtype),
             interpret=interpret,
-        )(packed, wq2)
+        )(packed, jnp.asarray(wq, jnp.uint32).reshape(c))
 
 
 @functools.partial(jax.jit, static_argnames=("fanout", "sibling",
                                              "use_masks", "interpret",
                                              "block_rows", "block_groups"))
 def masked_partial_sum_2d(words, keys, signs, *, fanout: int, sibling: int,
-                          use_masks: bool = True, interpret: bool = True,
+                          use_masks: bool = True, interpret: bool,
                           block_rows: int = BLOCK_ROWS,
                           block_groups: int = BLOCK_GROUPS):
     """Interior sub-aggregate: (C, R, 512) child word partials -> (C/fanout,
@@ -250,36 +165,21 @@ def masked_partial_sum_2d(words, keys, signs, *, fanout: int, sibling: int,
                          f"{fanout} — pad before the kernel")
     g = c // fanout
     word_bits = 16 if words.dtype == jnp.uint16 else 32
-    keys = jnp.asarray(keys, jnp.uint32)
-    signs = jnp.asarray(signs, jnp.int32)
-    kern_kw = dict(fanout=fanout, word_bits=word_bits, use_masks=use_masks,
-                   sibling=sibling)
     kind = ("partial_sum_masked16" if word_bits == 16
             else "partial_sum_masked")
+    y_spec = pl.BlockSpec((block_groups * fanout, block_rows, wide),
+                          lambda i, k: (k, i, 0))
+    out_spec = pl.BlockSpec((block_groups, block_rows, wide),
+                            lambda i, k: (k, i, 0))
     with tprof.kernel_scope(kind, rows, fanout, interpret):
-        if block_rows >= rows and block_groups >= g:
-            return pl.pallas_call(
-                functools.partial(_masked_partial_kernel, gridded=False,
-                                  **kern_kw),
-                in_specs=[pl.BlockSpec(words.shape, None),
-                          pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec((g, rows, wide), None),
-                out_shape=jax.ShapeDtypeStruct((g, rows, wide), words.dtype),
-                interpret=interpret,
-            )(words, keys, signs)
-        grid = (rows // block_rows, g // block_groups)
-        y_spec = pl.BlockSpec((block_groups * fanout, block_rows, wide),
-                              lambda i, k: (k, i, 0))
-        out_spec = pl.BlockSpec((block_groups, block_rows, wide),
-                                lambda i, k: (k, i, 0))
         return pl.pallas_call(
-            functools.partial(_masked_partial_kernel, gridded=True, **kern_kw),
-            grid=grid,
-            in_specs=[y_spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            functools.partial(_masked_partial_kernel, fanout=fanout,
+                              word_bits=word_bits, use_masks=use_masks,
+                              sibling=sibling, g_total=keys.shape[0]),
+            grid=(rows // block_rows, g // block_groups),
+            in_specs=[y_spec, SMEM, SMEM],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((g, rows, wide), words.dtype),
             interpret=interpret,
-        )(words, keys, signs)
+        )(words, jnp.asarray(keys, jnp.uint32).reshape(-1),
+          jnp.asarray(signs, jnp.int32).reshape(-1))

@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 256          # (256, 128) fp32 tile = 128 KiB / input → fits VMEM
@@ -42,7 +43,7 @@ def _kernel_round1(q_ref, p0_ref, alpha_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def ternary_encode_2d(q, p1, p2, beta, *, interpret: bool = True,
+def ternary_encode_2d(q, p1, p2, beta, *, interpret: bool,
                       block_rows: int = BLOCK_ROWS):
     """q/p1/p2 (R, 128) with R % block_rows == 0 → int8 (R, 128)."""
     rows = q.shape[0]
@@ -52,7 +53,7 @@ def ternary_encode_2d(q, p1, p2, beta, *, interpret: bool = True,
         _kernel,
         grid=grid,
         in_specs=[spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.int8),
         interpret=interpret,
@@ -60,7 +61,7 @@ def ternary_encode_2d(q, p1, p2, beta, *, interpret: bool = True,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def ternary_encode_round1_2d(q, p0, alpha, *, interpret: bool = True,
+def ternary_encode_round1_2d(q, p0, alpha, *, interpret: bool,
                              block_rows: int = BLOCK_ROWS):
     rows = q.shape[0]
     grid = (rows // block_rows,)
@@ -68,7 +69,7 @@ def ternary_encode_round1_2d(q, p0, alpha, *, interpret: bool = True,
     return pl.pallas_call(
         _kernel_round1,
         grid=grid,
-        in_specs=[spec, spec, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[spec, spec, pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.int8),
         interpret=interpret,

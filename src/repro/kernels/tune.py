@@ -4,7 +4,7 @@ Which (block_rows, block_workers) plan wins is a property of the *backend*,
 not the math: on TPU the grid must tile VMEM (small row blocks, one worker
 per step so the master's memory stays O(block)); under cpu-interpret every
 grid step pays the interpreter's full block machinery, so the fastest plan
-is the one with the fewest steps (whole-operand blocks, no grid). Every
+is the one with the fewest steps (whole-row blocks, a (1, 1) grid). Every
 plan computes bitwise-identical results (the uplink is elementwise; the
 master accumulates workers in a fixed sequential order), so tuning is free
 to pick purely on time.
@@ -91,12 +91,21 @@ def _emit_sweep(kind, rows, n, backend, timings, best) -> None:
         _TRACE_HOOK(kind, rows, n, backend, timings, best)
 
 
-def backend_tag(interpret: bool | None = None) -> str:
-    """The table's backend key: 'cpu-interpret' for interpret mode (the
-    hermetic-container default), else the real jax backend ('tpu', ...)."""
+def use_interpreter(interpret: bool | None = None) -> bool:
+    """THE interpret-mode decision for every Pallas launch: an explicit
+    request wins; otherwise kernels run in the Pallas interpreter only on
+    the ``cpu`` platform and compile (Mosaic) everywhere else — a chip
+    never falls back to the interpreter."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return "cpu-interpret" if interpret else jax.default_backend()
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
+def backend_tag(interpret: bool | None = None) -> str:
+    """The table's backend key: 'cpu-interpret' for interpret mode, else
+    the real jax backend ('tpu', ...)."""
+    return ("cpu-interpret" if use_interpreter(interpret)
+            else jax.default_backend())
 
 
 def fit_block_rows(rows: int, want: int) -> int:
@@ -137,7 +146,7 @@ def default_plan(kind: str, rows: int, n_workers: int = 1,
     """The untimed heuristic: fewest steps on cpu-interpret (per-step
     machinery dominates), VMEM-sized O(block) tiles elsewhere. For the
     partial-sum kinds ``n_workers`` holds the fanout and ``block_workers``
-    means output groups per grid step — the cpu one-shot wants ALL groups
+    means output groups per grid step — the cpu single-step plan wants ALL groups
     (the ops wrapper clamps to the level width)."""
     backend = backend or backend_tag()
     if backend == "cpu-interpret":
@@ -225,7 +234,7 @@ def _time_us(fn: Callable, reps: int) -> float:
 
 
 def _candidate_plans(rows: int, n: int, backend: str) -> list[dict]:
-    """Small, deduplicated sweep: the one-shot plan, whole-row blocks with
+    """Small, deduplicated sweep: the single-step plan, whole-row blocks with
     worker sub-blocks, and VMEM-tile plans."""
     cands = [
         {"block_rows": rows, "block_workers": n},            # one step
@@ -273,7 +282,7 @@ def autotune_stacked(rows: int, n_workers: int, *,
     winner in the table and returns the full sweep record. ``rows`` is the
     kernel-view row count (flat rows / 4)."""
     from repro.kernels import fused_wire as fw
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     k = jax.random.PRNGKey(seed)
     q = jax.random.normal(k, (n_workers, rows, fw.LANES * fw.PACK))
     p1 = jax.random.normal(jax.random.fold_in(k, 1),
@@ -296,7 +305,7 @@ def autotune_master(rows: int, n_workers: int, *,
                     seed: int = 0) -> dict:
     """Timed sweep of the accumulating-master plans for (rows, N)."""
     from repro.kernels import fused_wire as fw
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     k = jax.random.PRNGKey(seed)
     wide = fw.LANES * fw.PACK
     q = jax.random.normal(k, (rows, wide))
@@ -344,7 +353,7 @@ def autotune_masked_uplink(rows: int, n_workers: int, *,
     one wire modulus; fills the ``uplink_masked16``/``uplink_masked`` kind
     by ``word_bits``."""
     from repro.kernels import masked_wire as mw
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     q, p1, p2, keys, signs, rrk, wq = _masked_inputs(rows, n_workers, seed,
                                                      word_bits)
 
@@ -365,7 +374,7 @@ def autotune_masked_master(rows: int, n_workers: int, *,
     """Timed sweep of the sum-then-unmask master plans for (rows, N) at one
     wire modulus."""
     from repro.kernels import masked_wire as mw
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     q, p1, p2, keys, signs, rrk, wq = _masked_inputs(rows, n_workers, seed,
                                                      word_bits)
     word = jnp.uint16 if word_bits == 16 else jnp.uint32
@@ -395,7 +404,7 @@ def autotune_partial_sum(rows: int, fanout: int, n_children: int, *,
     per grid step (clamped to the level width by the ops wrappers)."""
     from repro.kernels import partial_sum as psk
     from repro.privacy import masking as pvm
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     backend = backend_tag(itp)
     g = -(-n_children // fanout)
     pad_c = g * fanout
@@ -464,7 +473,7 @@ def autotune_mask_repair(rows: int, n_pairs: int, *,
     zero-skip path a real faulted round exercises."""
     from repro.kernels import masked_wire as mw
     from repro.privacy import masking as pvm
-    itp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    itp = use_interpreter(interpret)
     backend = backend_tag(itp)
     word = jnp.uint16 if word_bits == 16 else jnp.uint32
     y = jax.random.bits(jax.random.PRNGKey(seed), (rows, 512),

@@ -73,10 +73,7 @@ def constrain(x, raw_spec):
             spec.append(axs if len(axs) > 1 else axs[0])
         else:
             spec.append(None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def residual(x):

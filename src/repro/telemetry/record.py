@@ -80,10 +80,12 @@ class TelemetryCarry(NamedTuple):
 
     @classmethod
     def zero(cls) -> "TelemetryCarry":
-        z = jnp.asarray(0, jnp.int32)
-        return cls(rounds=z, sampled=z, used=z, dead=z, pre_uplink=z,
-                   recovered=z, degraded=z,
-                   cost_sum=jnp.asarray(0.0, jnp.float32))
+        # One buffer per field: the carry is donated into jitted round
+        # programs, and a buffer donated twice is an error on the chip.
+        z = lambda: jnp.zeros((), jnp.int32)
+        return cls(rounds=z(), sampled=z(), used=z(), dead=z(),
+                   pre_uplink=z(), recovered=z(), degraded=z(),
+                   cost_sum=jnp.zeros((), jnp.float32))
 
     def add(self, rec: RoundTelemetry) -> "TelemetryCarry":
         """Fold one round's record into the running totals (traceable)."""

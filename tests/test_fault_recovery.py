@@ -400,7 +400,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import Mesh
+from repro.launch.mesh import make_mesh
 from repro.fed.distributed import build_fed_sync, fed_state_init
 from repro.fed.faults import FaultPlan
 from repro.privacy import PrivacySpec, effective_masks
@@ -420,7 +420,7 @@ plan = FaultPlan(seed=11, drop_after_uplink=0.3)
 out = {}
 for fed, model, tree in ((4, 2, None), (8, 1, TreeSpec(fanout=2))):
     devs = np.array(jax.devices()[: fed * model]).reshape(fed, model)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
     F = fed
     sizes = jnp.linspace(50.0, 200.0, F)
     costs = jnp.linspace(0.9, 0.5, F)

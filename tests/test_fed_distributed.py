@@ -19,8 +19,9 @@ from repro.models import build_model
 from repro.fed.distributed import build_fed_step, build_fed_sync, fed_state_init
 from repro.core.update import master_update_tree
 from repro.core.ternary import ternarize_tree, ternarize_tree_round1
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("fedpc-paper")
 m = build_model(cfg)
 params = m.init(jax.random.PRNGKey(0))
@@ -28,11 +29,7 @@ F = 4
 sizes = jnp.array([100.0, 200.0, 150.0, 50.0])
 out = {}
 
-try:
-    mesh_ctx = jax.set_mesh(mesh)        # jax >= 0.5
-except AttributeError:
-    mesh_ctx = mesh                       # Mesh is a context manager on 0.4
-with mesh_ctx:
+with jax.set_mesh(mesh):
     # --- strategies agree with each other and with the reference math ----
     state = fed_state_init(params, F)
     state["round"] = jnp.asarray(3, jnp.int32)       # exercise Eq.(5) branch

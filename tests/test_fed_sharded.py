@@ -16,7 +16,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import Mesh
+from repro.launch.mesh import make_mesh
 from repro.fed.distributed import build_fed_sync, fed_state_init
 
 k = jax.random.PRNGKey(0)
@@ -33,7 +33,7 @@ def tree_max_diff(a, b):
 
 for fed, model in ((4, 2), (2, 4), (2, 2), (8, 1)):
     devs = np.array(jax.devices()[: fed * model]).reshape(fed, model)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
     F = fed
     sizes = jnp.linspace(50.0, 200.0, F)
     costs = jnp.linspace(0.9, 0.5, F)

@@ -31,7 +31,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import Mesh
+from repro.launch.mesh import make_mesh
 from repro.core.privacy import LeakageLedger
 from repro.fed.distributed import build_fed_sync, fed_state_init
 from repro.privacy import PrivacySpec
@@ -56,7 +56,7 @@ SPECS = {
 
 for fed, model in ((4, 2), (2, 4), (8, 1)):
     devs = np.array(jax.devices()[: fed * model]).reshape(fed, model)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
     F = fed
     sizes = jnp.linspace(50.0, 200.0, F)
     costs = jnp.linspace(0.9, 0.5, F)
@@ -118,7 +118,7 @@ for fed, model in ((4, 2), (2, 4), (8, 1)):
 from repro.privacy import collective_payloads
 from repro.core import flat as fl
 devs = np.array(jax.devices()).reshape(4, 2)
-mesh = Mesh(devs, ("data", "model"))
+mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
 F = 4
 sizes = jnp.linspace(50.0, 200.0, F)
 costs = jnp.linspace(0.9, 0.5, F)

@@ -197,11 +197,10 @@ def test_fedavg_mask_renormalizes_over_participants():
     sampled workers only, shares renormalized (the fedavg branch has no
     collectives, so a 1x1 mesh suffices)."""
     import jax.numpy as jnp
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro.fed.distributed import build_fed_sync, fed_state_init
 
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     params = {"w": jnp.arange(8.0)}
     F = 4
     params_F = {"w": jnp.stack([params["w"] + i for i in range(F)])}

@@ -448,10 +448,10 @@ def test_simulator_privacy_with_partial_participation():
 def test_fed_sync_rejects_privacy_with_fedavg():
     """fedavg psums full-precision params — combining it with an active
     PrivacySpec must fail loudly, not silently run a plaintext wire."""
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro.fed.distributed import build_fed_sync
     devs = np.array(jax.devices()[:1]).reshape(1, 1)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
     with pytest.raises(ValueError, match="fedavg"):
         build_fed_sync(None, mesh, "data", "fedavg",
                        privacy=PrivacySpec())

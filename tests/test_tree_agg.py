@@ -279,21 +279,16 @@ def test_audit_passes_on_masked_tree_round():
 def test_demasked_partial_below_root_raises():
     """A signed-int (= de-masked, de-biased) buffer crossing a fed
     collective is the LeakageError the extended audit exists to catch."""
-    from jax.sharding import Mesh
-    mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("f",))
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("f",), devices=jax.devices("cpu")[:1])
 
     def leaky(x):
         body = lambda v: jax.lax.psum(
             jax.lax.bitcast_convert_type(v, jnp.int32), "f")
-        sm = jax.shard_map if hasattr(jax, "shard_map") else None
-        if sm is not None:
-            from jax.sharding import PartitionSpec as P
-            return sm(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                      axis_names=frozenset({"f"}), check_vma=False)(x)
-        from jax.experimental.shard_map import shard_map as _sm
-        from jax.sharding import PartitionSpec as P
-        return _sm(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_rep=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                             axis_names=frozenset({"f"}),
+                             check_vma=False)(x)
 
     words = jnp.zeros((8, 512), jnp.uint32)
     with pytest.raises(LeakageError, match="below the root"):
@@ -367,7 +362,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import Mesh
+from repro.launch.mesh import make_mesh
 from repro.core.tree import TreeSpec
 from repro.fed.distributed import build_fed_sync, fed_state_init
 from repro.privacy import PrivacySpec
@@ -385,7 +380,7 @@ def tree_max_diff(a, b):
 
 for fed, model in ((4, 2), (2, 4)):
     devs = np.array(jax.devices()[: fed * model]).reshape(fed, model)
-    mesh = Mesh(devs, ("data", "model"))
+    mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
     F = fed
     sizes = jnp.linspace(50.0, 200.0, F)
     costs = jnp.linspace(0.9, 0.5, F)
@@ -413,7 +408,7 @@ for fed, model in ((4, 2), (2, 4)):
 
 # validation: the mesh tree needs the masked wire and power-of-two shapes
 devs = np.array(jax.devices()[:4]).reshape(4, 1)
-mesh = Mesh(devs, ("data", "model"))
+mesh = make_mesh(devs.shape, ("data", "model"), devices=list(devs.flat))
 for kwargs, tag in ((dict(), "plain"),
                     (dict(privacy=PrivacySpec(),
                           tree_fanout=3), "fanout3")):
