@@ -848,10 +848,6 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes for CI; writes BENCH_kernels_smoke.json "
                          "(artifact) instead of BENCH_kernels.json")
-    ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="capture a jax.profiler trace of the whole bench "
-                         "under DIR (kernel launches are named after their "
-                         "tuner keys via telemetry.profile.kernel_scope)")
     ap.add_argument("--trace", metavar="PATH", default=None,
                     help="stream every autotune sweep's timed plans to a "
                          "telemetry JSONL trace at PATH (one plan event per "
@@ -863,7 +859,6 @@ if __name__ == "__main__":
     enable_compile_cache()
 
     from repro.kernels import tune as _tune
-    from repro.telemetry import profile as _tprof
     from repro.telemetry import trace as _tmt
     with ExitStack() as stack:
         if cli.trace:
@@ -871,6 +866,4 @@ if __name__ == "__main__":
                 _tmt.TraceWriter(cli.trace, source="kernels_bench"))
             _tune.set_trace_writer(_tmt.plan_emitter(writer.emit))
             stack.callback(_tune.set_trace_writer, None)
-        if cli.profile:
-            stack.enter_context(_tprof.profile_session(cli.profile))
         run(smoke=cli.smoke)

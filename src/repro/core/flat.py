@@ -143,23 +143,27 @@ def layout_of(tree: PyTree, shards: int = 1) -> FlatLayout:
 
 
 def flatten_tree(tree: PyTree, layout: FlatLayout) -> jax.Array:
-    """Pytree → padded (rows, 128) float32 buffer."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    flat = jnp.concatenate(
-        [l.reshape(-1).astype(jnp.float32) for l in leaves])
-    flat = jnp.pad(flat, (0, layout.padded - layout.n))
-    return flat.reshape(layout.rows, LANES)
+    """Pytree → padded (rows, 128) float32 buffer (device scope
+    ``fed/flatten``)."""
+    with jax.named_scope("fed/flatten"):
+        leaves = jax.tree_util.tree_leaves(tree)
+        flat = jnp.concatenate(
+            [l.reshape(-1).astype(jnp.float32) for l in leaves])
+        flat = jnp.pad(flat, (0, layout.padded - layout.n))
+        return flat.reshape(layout.rows, LANES)
 
 
 def unflatten_tree(buf: jax.Array, layout: FlatLayout) -> PyTree:
-    """Padded (rows, 128) buffer → pytree (leaves cast back to their dtypes)."""
-    flat = buf.reshape(-1)
-    leaves = [
-        jax.lax.slice(flat, (o,), (o + s,)).reshape(shape).astype(dt)
-        for o, s, shape, dt in zip(layout.offsets, layout.sizes,
-                                   layout.shapes, layout.dtypes)
-    ]
-    return jax.tree_util.tree_unflatten(layout.treedef, leaves)
+    """Padded (rows, 128) buffer → pytree (leaves cast back to their dtypes;
+    device scope ``fed/unflatten``)."""
+    with jax.named_scope("fed/unflatten"):
+        flat = buf.reshape(-1)
+        leaves = [
+            jax.lax.slice(flat, (o,), (o + s,)).reshape(shape).astype(dt)
+            for o, s, shape, dt in zip(layout.offsets, layout.sizes,
+                                       layout.shapes, layout.dtypes)
+        ]
+        return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
 def flatten_stacked(tree_F: PyTree, layout: FlatLayout) -> jax.Array:

@@ -30,6 +30,7 @@ TPU-mesh counterpart with the same math as collectives is
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -38,6 +39,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import baselines as bl
 from repro.core import fedpc as fp
@@ -276,7 +278,9 @@ class FedSimulator:
                       masks: np.ndarray | None, model_bytes: int,
                       ledger_done: bool, records=None,
                       driver: str = "run_fedpc",
-                      check_costs: bool = True) -> SimResult:
+                      check_costs: bool = True,
+                      span: Callable[[str], Any] = contextlib.nullcontext
+                      ) -> SimResult:
         """The ONE post-run device→host fetch: pilot history, costs and the
         stacked telemetry records come back together; ledger, byte
         accounting and trace assembly are host work.
@@ -286,100 +290,107 @@ class FedSimulator:
         ``telemetry.trace.build_trace`` cross-checks the device-recorded
         counts and the derived bytes against it — any divergence raises
         ``TelemetryMismatch`` instead of returning a wrong ledger.
+
+        ``span(step)`` wraps the three steps (``"wait"``, ``"ledger"``,
+        ``"trace"``): the scan driver passes its ``fed/scan/<step>`` host
+        spans; the default (the Python-loop driver) marks nothing.
         """
-        pilots = np.asarray(jnp.stack(k_stars))
-        costs_mat = np.asarray(jnp.stack(raw_costs))        # (R, N)
-        if not ledger_done:
-            self._backfill_ledger(t0, pilots, masks)
-        spec = self.fed_cfg.privacy
-        masked_wire = spec is not None and spec.active
-        codes_mat = self._fault_codes(t0, len(pilots))
-        host_rounds: list[dict] = []
-        for i in range(len(pilots)):
-            row = np.ones(self.n) if masks is None else masks[i]
-            # The reported round cost averages only workers whose report
-            # the master USED: sampled, not faulted, and (masked wire) in
-            # a viable sibling group. (The scan driver's costs_mat carries
-            # prev-round values for the excluded, the Python driver their
-            # never-delivered local measurements — both are masked out
-            # here, keeping the drivers bitwise.)
-            n_recoverable = 0
-            if codes_mat is None:
-                eff = row
-            elif masked_wire:
-                live_eff, _, recoverable = self._fault_split(
-                    row, codes_mat[i])
-                eff = row * live_eff
-                n_recoverable = int(recoverable.sum())
-            else:
-                eff = row * (codes_mat[i] == ft.FAULT_NONE)
-            if np.sum(eff) == 0:   # every report lost: cost track carries
-                res.costs.append(res.costs[-1] if res.costs
-                                 else float("inf"))
-            else:
-                vals = np.where(eff > 0, costs_mat[i], 0.0)
-                res.costs.append(float(np.average(
-                    vals, weights=self.sizes * eff)))
-            res.pilot_history.append(int(pilots[i]))
-            n_part = int(np.sum(row > 0))
-            if self.fed_cfg.tree is not None:
-                wire_bytes = proto.fedpc_tree_bytes_per_round(
-                    model_bytes, n_part, self.fed_cfg.tree.fanout,
-                    levels=self.fed_cfg.tree.levels,
-                    word_bits=spec.modulus_bits if masked_wire else None)
-            elif masked_wire:
-                wire_bytes = proto.fedpc_masked_bytes_per_round(
-                    model_bytes, n_part, word_bits=spec.modulus_bits)
-            else:
-                wire_bytes = proto.fedpc_bytes_per_round(
-                    model_bytes, n_part)
-            rec_bytes = 0.0
-            if codes_mat is not None:
-                codes = codes_mat[i]
-                # pre-uplink deaths never spent their uplink bytes
-                n_pre = int(np.sum((row > 0) & (codes == ft.DROP_BEFORE)))
-                leaf_bits = (float(spec.modulus_bits) if masked_wire
-                             else 2.0)
-                wire_bytes -= model_bytes * n_pre * leaf_bits / 32.0
-                if (spec is not None and spec.masking_on
-                        and spec.recovery_threshold is not None):
-                    g = (self.fed_cfg.tree.fanout
-                         if self.fed_cfg.tree is not None else None)
-                    _, _, recoverable = self._fault_split(row, codes)
-                    rec_bytes = (
-                        proto.recovery_dealing_bytes_per_round(self.n, g)
-                        + proto.recovery_reconstruction_bytes(
-                            int(recoverable.sum()),
-                            spec.recovery_threshold, g,
-                            n_workers=self.n))
-            host_rounds.append({
-                "row": row > 0,
-                "codes": None if codes_mat is None else codes_mat[i],
-                "used": np.asarray(eff) > 0,
-                "n_recoverable": n_recoverable,
-                "pilot": int(pilots[i]), "cost": res.costs[-1],
-                "wire_bytes": wire_bytes, "recovery_bytes": rec_bytes})
-        if records is not None:
-            tree = self.fed_cfg.tree
-            meta = tmt.trace_meta(
-                source="fed_simulator", algorithm="fedpc", driver=driver,
-                n_workers=self.n, t0=t0, rounds=len(pilots),
-                model_bytes=model_bytes,
-                wire="masked" if masked_wire else "plain",
-                masking=bool(spec is not None and spec.masking_on),
-                modulus_bits=spec.modulus_bits if masked_wire else 0,
-                fanout=tree.fanout if tree is not None else 0,
-                levels=(tree.levels or 0) if tree is not None else 0,
-                recovery_threshold=((spec.recovery_threshold or 0)
-                                    if spec is not None else 0),
-                faults_active=codes_mat is not None)
-            recs_host = jax.tree_util.tree_map(np.asarray, records)
-            res.telemetry = tmt.build_trace(meta, recs_host, host_rounds,
-                                            check_costs=check_costs)
-        else:       # telemetry disabled on the carry: legacy byte lists
-            for h in host_rounds:
-                res._bytes.append(h["wire_bytes"])
-                res._recovery_bytes.append(h["recovery_bytes"])
+        with span("wait"):      # the first fetch: blocks on the device
+            pilots = np.asarray(jnp.stack(k_stars))
+            costs_mat = np.asarray(jnp.stack(raw_costs))        # (R, N)
+        with span("ledger"):
+            if not ledger_done:
+                self._backfill_ledger(t0, pilots, masks)
+            spec = self.fed_cfg.privacy
+            masked_wire = spec is not None and spec.active
+            codes_mat = self._fault_codes(t0, len(pilots))
+            host_rounds: list[dict] = []
+            for i in range(len(pilots)):
+                row = np.ones(self.n) if masks is None else masks[i]
+                # The reported round cost averages only workers whose report
+                # the master USED: sampled, not faulted, and (masked wire) in
+                # a viable sibling group. (The scan driver's costs_mat carries
+                # prev-round values for the excluded, the Python driver their
+                # never-delivered local measurements — both are masked out
+                # here, keeping the drivers bitwise.)
+                n_recoverable = 0
+                if codes_mat is None:
+                    eff = row
+                elif masked_wire:
+                    live_eff, _, recoverable = self._fault_split(
+                        row, codes_mat[i])
+                    eff = row * live_eff
+                    n_recoverable = int(recoverable.sum())
+                else:
+                    eff = row * (codes_mat[i] == ft.FAULT_NONE)
+                if np.sum(eff) == 0:   # every report lost: cost track carries
+                    res.costs.append(res.costs[-1] if res.costs
+                                     else float("inf"))
+                else:
+                    vals = np.where(eff > 0, costs_mat[i], 0.0)
+                    res.costs.append(float(np.average(
+                        vals, weights=self.sizes * eff)))
+                res.pilot_history.append(int(pilots[i]))
+                n_part = int(np.sum(row > 0))
+                if self.fed_cfg.tree is not None:
+                    wire_bytes = proto.fedpc_tree_bytes_per_round(
+                        model_bytes, n_part, self.fed_cfg.tree.fanout,
+                        levels=self.fed_cfg.tree.levels,
+                        word_bits=spec.modulus_bits if masked_wire else None)
+                elif masked_wire:
+                    wire_bytes = proto.fedpc_masked_bytes_per_round(
+                        model_bytes, n_part, word_bits=spec.modulus_bits)
+                else:
+                    wire_bytes = proto.fedpc_bytes_per_round(
+                        model_bytes, n_part)
+                rec_bytes = 0.0
+                if codes_mat is not None:
+                    codes = codes_mat[i]
+                    # pre-uplink deaths never spent their uplink bytes
+                    n_pre = int(np.sum((row > 0) & (codes == ft.DROP_BEFORE)))
+                    leaf_bits = (float(spec.modulus_bits) if masked_wire
+                                 else 2.0)
+                    wire_bytes -= model_bytes * n_pre * leaf_bits / 32.0
+                    if (spec is not None and spec.masking_on
+                            and spec.recovery_threshold is not None):
+                        g = (self.fed_cfg.tree.fanout
+                             if self.fed_cfg.tree is not None else None)
+                        _, _, recoverable = self._fault_split(row, codes)
+                        rec_bytes = (
+                            proto.recovery_dealing_bytes_per_round(self.n, g)
+                            + proto.recovery_reconstruction_bytes(
+                                int(recoverable.sum()),
+                                spec.recovery_threshold, g,
+                                n_workers=self.n))
+                host_rounds.append({
+                    "row": row > 0,
+                    "codes": None if codes_mat is None else codes_mat[i],
+                    "used": np.asarray(eff) > 0,
+                    "n_recoverable": n_recoverable,
+                    "pilot": int(pilots[i]), "cost": res.costs[-1],
+                    "wire_bytes": wire_bytes, "recovery_bytes": rec_bytes})
+        with span("trace"):
+            if records is not None:
+                tree = self.fed_cfg.tree
+                meta = tmt.trace_meta(
+                    source="fed_simulator", algorithm="fedpc", driver=driver,
+                    n_workers=self.n, t0=t0, rounds=len(pilots),
+                    model_bytes=model_bytes,
+                    wire="masked" if masked_wire else "plain",
+                    masking=bool(spec is not None and spec.masking_on),
+                    modulus_bits=spec.modulus_bits if masked_wire else 0,
+                    fanout=tree.fanout if tree is not None else 0,
+                    levels=(tree.levels or 0) if tree is not None else 0,
+                    recovery_threshold=((spec.recovery_threshold or 0)
+                                        if spec is not None else 0),
+                    faults_active=codes_mat is not None)
+                recs_host = jax.tree_util.tree_map(np.asarray, records)
+                res.telemetry = tmt.build_trace(meta, recs_host, host_rounds,
+                                                check_costs=check_costs)
+            else:       # telemetry disabled on the carry: legacy byte lists
+                for h in host_rounds:
+                    res._bytes.append(h["wire_bytes"])
+                    res._recovery_bytes.append(h["recovery_bytes"])
         res.params = fl.unflatten_tree(state.buf_p1, layout)
         res.round_state = state
         return res
@@ -514,89 +525,104 @@ class FedSimulator:
         multiple of its batch size (no ragged last batch). The evasion
         defence (per-round host behaviour) is not available here.
         """
-        if self.evade_streak:
-            raise ValueError("evade_streak requires the Python-loop driver "
-                             "(per-round host behaviour)")
-        cfg = self.fed_cfg
-        wire = self._wire_path(wire_block_rows, wire_block_workers)
-        layout = fl.layout_of(self.init_params)
-        resumed = state is not None
-        if state is None:
-            state = rd.init_round_state(self.init_params, self.n, layout,
-                                        privacy=cfg.privacy)
-        state = _own_state(state, resumed)
-        t0 = int(state.round)                 # one setup-time sync
-        masks, betas_arr = self._resolve_scenario(
-            participation, betas, rounds, participation_seed, t0)
-        model_bytes = proto.model_size_bytes(self.init_params)
-        params0 = fl.unflatten_tree(state.buf_p1, layout)
-        res = SimResult("fedpc", params0)
-        self._enforce_privacy("run_fedpc_scan", wire, state, betas_arr,
-                              has_mask=masks is not None)
+        with TraceAnnotation("fed/scan"):
+            if self.evade_streak:
+                raise ValueError("evade_streak requires the Python-loop driver "
+                                 "(per-round host behaviour)")
+            with TraceAnnotation("fed/scan/prepare"):
+                with TraceAnnotation("fed/scan/state"):
+                    cfg = self.fed_cfg
+                    wire = self._wire_path(wire_block_rows, wire_block_workers)
+                    layout = fl.layout_of(self.init_params)
+                    resumed = state is not None
+                    if state is None:
+                        state = rd.init_round_state(self.init_params, self.n,
+                                                    layout, privacy=cfg.privacy)
+                    state = _own_state(state, resumed)
+                    t0 = int(state.round)                 # one setup-time sync
+                    masks, betas_arr = self._resolve_scenario(
+                        participation, betas, rounds, participation_seed, t0)
+                    model_bytes = proto.model_size_bytes(self.init_params)
+                    params0 = fl.unflatten_tree(state.buf_p1, layout)
+                    res = SimResult("fedpc", params0)
+                with TraceAnnotation("fed/scan/audit"):
+                    self._enforce_privacy("run_fedpc_scan", wire, state,
+                                          betas_arr, has_mask=masks is not None)
 
-        # --- pre-draw every worker's batch schedule (host) --------------
-        # Only the sample INDICES are pre-drawn — (rounds, steps, bs) int32
-        # per worker; the shard itself lives on device once and the scan
-        # body gathers batches from it, so device memory stays
-        # O(shard + rounds·steps·bs·4B) instead of O(rounds · shard).
-        shards, index_schedules, steps_per_round = [], [], []
-        for k, w in enumerate(self.workers):
-            if not w.uniform_batches:
-                raise ValueError(
-                    f"worker {k}: scan driver needs batch_size "
-                    f"({w.loader.batch_size}) to divide the shard size "
-                    f"({w.loader.n}) — no ragged last batch under scan")
-            steps = w.cfg.local_epochs * w.loader.steps_per_epoch()
-            steps_per_round.append(steps)
-            rows = []
-            for i in range(rounds):
-                if masks is None or masks[i, k]:
-                    rows.append(np.stack(
-                        [sel for _ in range(w.cfg.local_epochs)
-                         for sel in w.loader.epoch_indices()]))
-                else:       # skipped round: loader rng untouched; the
-                    # gathered batch is masked out of all state anyway
-                    rows.append(np.zeros((steps, w.loader.batch_size),
-                                         np.int64))
-            index_schedules.append(jnp.asarray(np.stack(rows), jnp.int32))
-            shards.append(tuple(jnp.asarray(a) for a in w.loader.arrays))
-            if w.opt_state is None:
-                w.opt_state = w.opt.init(params0)
+                # --- pre-draw every worker's batch schedule (host) ----------
+                # Only the sample INDICES are pre-drawn — (rounds, steps, bs)
+                # int32 per worker; the shard itself lives on device once and
+                # the scan body gathers batches from it, so device memory stays
+                # O(shard + rounds·steps·bs·4B) instead of O(rounds · shard).
+                with TraceAnnotation("fed/scan/schedules"):
+                    shards, index_schedules, steps_per_round = [], [], []
+                    for k, w in enumerate(self.workers):
+                        if not w.uniform_batches:
+                            raise ValueError(
+                                f"worker {k}: scan driver needs batch_size "
+                                f"({w.loader.batch_size}) to divide the shard "
+                                f"size ({w.loader.n}) — no ragged last batch "
+                                f"under scan")
+                        steps = w.cfg.local_epochs * w.loader.steps_per_epoch()
+                        steps_per_round.append(steps)
+                        rows = []
+                        for i in range(rounds):
+                            if masks is None or masks[i, k]:
+                                rows.append(np.stack(
+                                    [sel for _ in range(w.cfg.local_epochs)
+                                     for sel in w.loader.epoch_indices()]))
+                            else:   # skipped round: loader rng untouched; the
+                                # gathered batch is masked out of all state
+                                rows.append(np.zeros(
+                                    (steps, w.loader.batch_size), np.int64))
+                        index_schedules.append(
+                            jnp.asarray(np.stack(rows), jnp.int32))
+                        shards.append(tuple(jnp.asarray(a)
+                                            for a in w.loader.arrays))
+                        if w.opt_state is None:
+                            w.opt_state = w.opt.init(params0)
 
-        worker_carry = tuple(
-            (w.opt_state, jnp.asarray(w.step, jnp.int32))
-            for w in self.workers)
-        masks_dev = None if masks is None else jnp.asarray(masks)
-        args = (state, worker_carry, tuple(index_schedules), tuple(shards),
-                masks_dev, jnp.asarray(self.sizes), betas_arr,
-                jnp.asarray(t0, jnp.int32))
-        key = (rounds, wire, masks is None, betas_arr is None)
-        prog = self.scan_programs.get(key)
-        if prog is None:
-            t_c = time.perf_counter()
-            # The history state and the workers' optimizer carry are both
-            # replaced by the program's outputs: donate them.
-            compiled = jax.jit(
-                partial(self._scan_body, wire, layout, rounds),
-                donate_argnums=(0, 1) if _should_donate() else ()
-            ).lower(*args).compile()
-            prog = ScanProgram(compiled, time.perf_counter() - t_c)
-            self.scan_programs[key] = prog
-        state, worker_carry, infos = prog.compiled(*args)
+                    worker_carry = tuple(
+                        (w.opt_state, jnp.asarray(w.step, jnp.int32))
+                        for w in self.workers)
+                    masks_dev = None if masks is None else jnp.asarray(masks)
+                    args = (state, worker_carry, tuple(index_schedules),
+                            tuple(shards), masks_dev, jnp.asarray(self.sizes),
+                            betas_arr, jnp.asarray(t0, jnp.int32))
+                key = (rounds, wire, masks is None, betas_arr is None)
+                prog = self.scan_programs.get(key)
+                if prog is None:
+                    with TraceAnnotation("fed/scan/compile"):
+                        t_c = time.perf_counter()
+                        # The history state and the workers' optimizer carry
+                        # are both replaced by the program's outputs: donate
+                        # them.
+                        compiled = jax.jit(
+                            partial(self._scan_body, wire, layout, rounds),
+                            donate_argnums=(0, 1) if _should_donate() else ()
+                        ).lower(*args).compile()
+                        prog = ScanProgram(compiled, time.perf_counter() - t_c)
+                        self.scan_programs[key] = prog
+            with TraceAnnotation("fed/scan/dispatch"):
+                state, worker_carry, infos = prog.compiled(*args)
 
-        # write back worker state (host bookkeeping, once)
-        for k, w in enumerate(self.workers):
-            w.opt_state = worker_carry[k][0]
-            part = rounds if masks is None else int(np.sum(masks[:, k] > 0))
-            w.step += steps_per_round[k] * part
+            with TraceAnnotation("fed/scan/finish"):
+                # write back worker state (host bookkeeping, once)
+                for k, w in enumerate(self.workers):
+                    w.opt_state = worker_carry[k][0]
+                    part = (rounds if masks is None
+                            else int(np.sum(masks[:, k] > 0)))
+                    w.step += steps_per_round[k] * part
 
-        k_stars = list(infos["k_star"])
-        raw_costs = list(infos["costs"])
-        return self._finish_fedpc(res, state, layout, t0, k_stars,
-                                  raw_costs, masks, model_bytes,
-                                  ledger_done=False,
-                                  records=infos["telemetry"],
-                                  driver="run_fedpc_scan")
+                k_stars = list(infos["k_star"])
+                raw_costs = list(infos["costs"])
+                return self._finish_fedpc(res, state, layout, t0, k_stars,
+                                          raw_costs, masks, model_bytes,
+                                          ledger_done=False,
+                                          records=infos["telemetry"],
+                                          driver="run_fedpc_scan",
+                                          span=lambda leaf: TraceAnnotation(
+                                              f"fed/scan/{leaf}"))
 
     def _scan_body(self, wire: rd.WirePath, layout: fl.FlatLayout,
                    rounds: int, state, worker_carry, index_schedules,

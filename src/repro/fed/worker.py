@@ -119,8 +119,9 @@ class Worker:
             p, os, s, tot = carry
             lr = self.lr_fn(s)
             (loss, _aux), grads = self.loss_and_grad(p, batch)
-            updates, os = self.opt.update(grads, os, p, lr)
-            p = opt_mod.apply_updates(p, updates)
+            with jax.named_scope("fed/train/optimizer"):
+                updates, os = self.opt.update(grads, os, p, lr)
+                p = opt_mod.apply_updates(p, updates)
             return (p, os, s + 1, tot + loss), None
 
         n_steps = jax.tree_util.tree_leaves(batches)[0].shape[0]

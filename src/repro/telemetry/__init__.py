@@ -9,7 +9,8 @@ traces with protocol-model byte cross-checks, and profiler hooks.
   ``core.protocol`` byte models, :func:`summarize` rollups, streaming
   :class:`TraceWriter` for tuner sweeps.
 * ``telemetry.profile`` — ``jax.named_scope`` kernel labels keyed like the
-  autotune table + an opt-in ``jax.profiler`` session helper.
+  autotune table, beside the round body's own device scopes; the
+  simulator driver's host spans are ``TraceAnnotation``s.
 * ``telemetry.report`` — CLI rendering round tables and per-kind rollups
   from a trace file (``python -m repro.telemetry.report trace.jsonl``).
 * ``telemetry.smoke`` — the CI smoke: a tiny traced federation written,
@@ -24,5 +25,5 @@ from repro.telemetry.trace import (  # noqa: F401
     validate_event, validate_trace, write_trace,
 )
 from repro.telemetry.profile import (  # noqa: F401
-    kernel_scope, profile_session, scope_name,
+    kernel_scope, scope_name,
 )

@@ -1,20 +1,28 @@
-"""Profiler integration: named kernel scopes + opt-in trace sessions.
+"""Profiler labels: named device scopes and host spans.
 
 Every kernel wrapper in ``repro.kernels.ops`` (and the tree/masked entry
 points it fronts) launches inside a :func:`kernel_scope` named after the
 tuner's table key — ``wire/<kind>/r<rows>n<N>/<backend>`` — so a real-TPU
 ``jax.profiler`` capture attributes device time to the same identities the
-autotuner plans and ``BENCH_kernels.json`` reports. ``jax.named_scope``
-annotates metadata only: it adds no jaxpr equations, so the round program
-still counts exactly two pallas launches and zero host syncs with scopes
-on (pinned by tests/test_telemetry.py).
+autotuner plans. The round body adds two more device scopes, as plain
+``jax.named_scope``: ``fed/train/optimizer`` around each local step's
+optimizer update (``Worker.scan_train``), and ``fed/flatten`` /
+``fed/unflatten`` around the flat-buffer conversions of ``core/flat.py``.
+A named scope annotates metadata only: it adds no jaxpr equations (the
+round program still counts exactly two pallas launches and zero host
+syncs, pinned by tests/test_telemetry.py), and it reaches the compiled
+program's HLO text as each instruction's ``op_name``, which is where a
+trace reduction looks it up (a device trace event names only its
+instruction).
 
-:func:`profile_session` wraps ``jax.profiler.start_trace/stop_trace`` as a
-context manager; ``benchmarks/kernels_bench.py --profile DIR`` drives it.
+Host work is labelled with ``jax.profiler.TraceAnnotation`` directly:
+a span on the profiler's host plane, on the same clock as the device's
+``XLA Ops`` line, so a device idle gap can be attributed to what the host
+was doing during it. ``FedSimulator.run_fedpc_scan`` marks its steps with
+them (``fed/scan`` and its children). With no profiler session active a
+span costs about a microsecond.
 """
 from __future__ import annotations
-
-from contextlib import contextmanager
 
 import jax
 
@@ -32,13 +40,3 @@ def kernel_scope(kind: str, rows: int, n: int = 1,
     """``jax.named_scope`` over a kernel launch, named by its tuner key."""
     return jax.named_scope(scope_name(kind, rows, n, interpret))
 
-
-@contextmanager
-def profile_session(logdir: str):
-    """Opt-in ``jax.profiler`` capture: every named kernel scope inside the
-    block lands in the trace under ``logdir`` (TensorBoard/Perfetto)."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()

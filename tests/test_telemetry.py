@@ -13,9 +13,16 @@ Contracts pinned here:
     re-derivation through ``core.protocol`` — flat, tree, masked-16/32
     and faulty-round runs (the SimResult byte views are the same data);
   * tuner sweeps emit one plan event per timed candidate;
+  * ``run_fedpc_scan``'s host spans reach a ``jax.profiler`` capture and
+    nest as documented; the round body's device scopes reach the compiled
+    program's ``op_name`` metadata and add no jaxpr equations;
   * the fault-code constants mirrored into ``telemetry.record`` (to
     avoid an import cycle) stay identical to ``repro.fed.faults``.
 """
+import contextlib
+import glob
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,7 +44,9 @@ from repro.models.mlp import init_mlp_classifier, mlp_loss_and_grad
 from repro.privacy.spec import PrivacySpec
 from repro.telemetry import record as tmr
 from repro.telemetry import trace as tmt
-from repro.utils import HOST_SYNC_PRIMITIVES, jaxpr_primitive_counts
+from repro.utils import (
+    HOST_SYNC_PRIMITIVES, iter_jaxpr_eqns, jaxpr_primitive_counts,
+)
 
 N = 6
 PER = 60
@@ -388,3 +397,119 @@ def test_plan_trace_writer_roundtrip(tmp_path):
     summary = tmt.summarize(events)
     assert summary.plans and not summary.rounds
     assert sum(e["best"] for e in summary.plans) == 1
+
+
+# ---------------------------------------------------------------------------
+# Profiler labels: host spans of the scan driver, device scopes of its body
+# ---------------------------------------------------------------------------
+
+PREPARE_LEAVES = ("fed/scan/state", "fed/scan/audit", "fed/scan/schedules",
+                  "fed/scan/compile")
+FINISH_LEAVES = ("fed/scan/wait", "fed/scan/ledger", "fed/scan/trace")
+
+
+@pytest.fixture(scope="module")
+def traced_scan(tmp_path_factory):
+    """Two masked, faulted scan calls under one profiler capture (the first
+    compiles): ``(calls, sim)``, ``calls`` holding each call's ``fed/``
+    spans as ``{name: (start_ns, end_ns)}``."""
+    sim = _make_sim(_faulty_cfg())
+    logdir = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(logdir)
+    try:
+        res = sim.run_fedpc_scan(rounds=2)
+        res = sim.run_fedpc_scan(rounds=2, state=res.round_state)
+        jax.block_until_ready(res.params)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True))[-1]
+    spans = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                   for plane in jax.profiler.ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith("fed/"))
+    calls = [{n: (s, e) for s, e, n in spans if s >= c0 and e <= c1}
+             for c0, c1, name in spans if name == "fed/scan"]
+    return calls, sim
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_scan_host_spans_nest(traced_scan):
+    calls, _ = traced_scan
+    assert len(calls) == 2
+    for i, call in enumerate(calls):
+        want = {"fed/scan", "fed/scan/prepare", "fed/scan/dispatch",
+                "fed/scan/finish", *PREPARE_LEAVES, *FINISH_LEAVES}
+        if i:                   # served by the compiled program
+            want.discard("fed/scan/compile")
+        assert set(call) == want
+        for name, span in call.items():
+            assert _inside(span, call["fed/scan"]), name
+        for leaf in PREPARE_LEAVES[:3 if i else 4]:
+            assert _inside(call[leaf], call["fed/scan/prepare"]), leaf
+        for leaf in FINISH_LEAVES:
+            assert _inside(call[leaf], call["fed/scan/finish"]), leaf
+        # the call's steps follow each other in program order
+        order = ["fed/scan/prepare", "fed/scan/dispatch", "fed/scan/finish"]
+        assert all(call[a][1] <= call[b][0]
+                   for a, b in zip(order, order[1:]))
+        leaves = [n for n in PREPARE_LEAVES + FINISH_LEAVES if n in call]
+        assert all(call[a][1] <= call[b][0]
+                   for a, b in zip(leaves, leaves[1:]))
+
+
+def test_scan_program_carries_device_scopes(traced_scan):
+    _, sim = traced_scan
+    (prog,) = sim.scan_programs.values()
+    op_names = [line.split('op_name="', 1)[1].split('"', 1)[0]
+                for line in prog.compiled.as_text().splitlines()
+                if 'op_name="' in line]
+    for scope in ("fed/train/optimizer/", "fed/flatten/", "fed/unflatten/"):
+        assert any(scope in n for n in op_names), scope
+
+
+def test_device_scopes_add_no_equations(monkeypatch):
+    """The scan program's jaxpr, equation for equation, with the round
+    body's named scopes and with every named scope turned off."""
+    sim = _make_sim(_faulty_cfg())
+    rounds, t0 = 2, 1
+    layout = fl.layout_of(sim.init_params)
+    masks, betas_arr = sim._resolve_scenario(None, None, rounds, 0, t0)
+    state = rd.init_round_state(sim.init_params, N, layout,
+                                privacy=sim.fed_cfg.privacy)
+    worker_carry = tuple((w.opt.init(sim.init_params),
+                          jnp.asarray(0, jnp.int32)) for w in sim.workers)
+    schedules = tuple(jnp.zeros(
+        (rounds, w.cfg.local_epochs * w.loader.steps_per_epoch(),
+         w.loader.batch_size), jnp.int32) for w in sim.workers)
+    shards = tuple(tuple(jnp.asarray(a) for a in w.loader.arrays)
+                   for w in sim.workers)
+    wire = sim._wire_path(None, None)
+    args = (state, worker_carry, schedules, shards, masks,
+            jnp.asarray(sim.sizes), betas_arr, jnp.asarray(t0, jnp.int32))
+
+    def eqn_counts():
+        # a new partial each time: make_jaxpr caches traces by function
+        body = partial(sim._scan_body, wire, layout, rounds)
+        jaxpr = jax.make_jaxpr(body)(*args)
+        counts: dict = {}
+        for eqn in iter_jaxpr_eqns(jaxpr.jaxpr):
+            name = eqn.primitive.name
+            counts[name] = counts.get(name, 0) + 1
+            if "fed/train/optimizer" in str(eqn.source_info.name_stack):
+                counts["in optimizer scope"] = counts.get(
+                    "in optimizer scope", 0) + 1
+        return counts
+
+    scoped = eqn_counts()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = eqn_counts()
+    assert scoped.pop("in optimizer scope") > 0
+    assert "in optimizer scope" not in plain
+    assert plain == scoped
+    assert scoped.get("pallas_call", 0) > 0
+    assert sum(scoped.get(p, 0) for p in HOST_SYNC_PRIMITIVES) == 0
