@@ -29,8 +29,11 @@ program, and fault codes are public control metadata, not wire payload.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.privacy import masking as pvm
 
@@ -94,3 +97,18 @@ class FaultPlan:
     def alive(self, t, n: int) -> jnp.ndarray:
         """(n,) float32 survival mask of round ``t``: 1 where no fault."""
         return (self.codes(t, n) == FAULT_NONE).astype(jnp.float32)
+
+    def code_matrix(self, t0: int, rounds: int, n: int) -> np.ndarray:
+        """(rounds, n) int32 host copy of rounds ``t0 .. t0+rounds-1``:
+        row ``i`` is ``codes(t0 + i, n)`` bitwise.
+
+        The same :meth:`codes` hash broadcast over a column of rounds, in
+        one compiled dispatch and one fetch. ``t0`` is traced, so every
+        later schedule of the same ``(rounds, n)`` reuses the program."""
+        return np.asarray(_code_matrix(self, np.uint32(t0), rounds, n))
+
+
+@partial(jax.jit, static_argnums=(0, 2, 3))
+def _code_matrix(plan: FaultPlan, t0, rounds: int, n: int) -> jax.Array:
+    t = t0 + jnp.arange(rounds, dtype=jnp.uint32)
+    return plan.codes(t[:, None], n)

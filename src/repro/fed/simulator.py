@@ -130,6 +130,10 @@ class FedSimulator:
         # has-betas): reused by every later run of that shape (a resumed
         # run does not recompile); their HLO is what a chip check inspects.
         self.scan_programs: dict[tuple, ScanProgram] = {}
+        # Host fault-schedule draws (one compiled FaultPlan.code_matrix
+        # dispatch each): one per FedPC call under an active plan, plus one
+        # per round under the evasion defence's live ledger.
+        self.fault_schedule_draws = 0
 
     # ------------------------------------------------------------------
     # FedPC shared plumbing
@@ -179,12 +183,13 @@ class FedSimulator:
     def _fault_codes(self, t0: int, n_rounds: int) -> np.ndarray | None:
         """(R, N) host copy of the fault schedule, or None without a plan.
         The plan is a pure function of (seed, round, worker), so the host
-        recomputes it — no extra device→host traffic."""
+        recomputes it in one compiled dispatch instead of fetching the
+        device's copy."""
         plan = self.fed_cfg.faults
         if plan is None or not plan.active:
             return None
-        return np.stack([np.asarray(plan.codes(t0 + i, self.n))
-                         for i in range(n_rounds)])
+        self.fault_schedule_draws += 1
+        return plan.code_matrix(t0, n_rounds, self.n)
 
     def _fault_split(self, row: np.ndarray, codes: np.ndarray
                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -235,16 +240,17 @@ class FedSimulator:
         self.ledger.record_audit(runtime, report)
 
     def _backfill_ledger(self, t0: int, pilots: np.ndarray,
-                         masks: np.ndarray | None) -> None:
+                         masks: np.ndarray | None,
+                         codes_mat: np.ndarray | None) -> None:
         """Record each round's uplink events after the fact — the ledger is
         host metadata, so it is reconstructed from the single post-run fetch
         of the on-device pilot history (§4.2 invariants unchanged). On the
         masked wire the master receives mod-2^modulus masked words, never
-        the per-worker 2-bit codes — the ledger records what crossed."""
+        the per-worker 2-bit codes — the ledger records what crossed.
+        ``codes_mat`` is the rounds' ``_fault_codes`` schedule."""
         spec = self.fed_cfg.privacy
         code_kind = ("masked_words" if spec is not None and spec.active
                      else "packed_ternary")
-        codes_mat = self._fault_codes(t0, len(pilots))
         recovery_on = (codes_mat is not None and spec is not None
                        and spec.masking_on
                        and spec.recovery_threshold is not None)
@@ -299,11 +305,11 @@ class FedSimulator:
             pilots = np.asarray(jnp.stack(k_stars))
             costs_mat = np.asarray(jnp.stack(raw_costs))        # (R, N)
         with span("ledger"):
+            codes_mat = self._fault_codes(t0, len(pilots))
             if not ledger_done:
-                self._backfill_ledger(t0, pilots, masks)
+                self._backfill_ledger(t0, pilots, masks, codes_mat)
             spec = self.fed_cfg.privacy
             masked_wire = spec is not None and spec.active
-            codes_mat = self._fault_codes(t0, len(pilots))
             host_rounds: list[dict] = []
             for i in range(len(pilots)):
                 row = np.ones(self.n) if masks is None else masks[i]
@@ -485,7 +491,8 @@ class FedSimulator:
 
             if self.evade_streak:     # defence needs the ledger live
                 k_host = int(info["k_star"])
-                self._backfill_ledger(t, np.asarray([k_host]), None)
+                self._backfill_ledger(t, np.asarray([k_host]), None,
+                                      self._fault_codes(t, 1))
             if eval_every and self.eval_fn and (t - t0 + 1) % eval_every == 0:
                 res.eval_history.append((t, self.eval_fn(params)))
 

@@ -292,6 +292,37 @@ def test_scan_rounds_realizes_faults_per_round():
                                   np.asarray(st.buf_p1))
 
 
+_SCHEDULE_PLANS = {
+    "drop_after": FaultPlan(seed=15, drop_after_uplink=0.25),
+    "all_types": FaultPlan(seed=3, drop_before_uplink=0.1,
+                           drop_after_uplink=0.25, straggler=0.2),
+    "inactive": FaultPlan(seed=7),
+}
+
+
+@pytest.mark.parametrize("n", [4, 6])
+@pytest.mark.parametrize("rounds", [1, 8])
+@pytest.mark.parametrize("t0", [0, 7, 2**20 + 3])
+@pytest.mark.parametrize("plan_name", sorted(_SCHEDULE_PLANS))
+def test_code_matrix_equals_per_round_codes(plan_name, t0, rounds, n):
+    """The one-dispatch schedule is the per-round ``codes`` hash, bitwise,
+    and a later ``t0`` of the same shape reuses its compiled program."""
+    from repro.fed.faults import _code_matrix
+    plan = _SCHEDULE_PLANS[plan_name]
+    got = plan.code_matrix(t0, rounds, n)
+    want = np.stack([np.asarray(plan.codes(t0 + i, n))
+                     for i in range(rounds)])
+    assert isinstance(got, np.ndarray) and got.dtype == np.int32
+    assert got.shape == (rounds, n)
+    np.testing.assert_array_equal(got, want)
+    compiled = _code_matrix._cache_size()
+    np.testing.assert_array_equal(
+        plan.code_matrix(t0 + rounds, rounds, n),
+        np.stack([np.asarray(plan.codes(t0 + rounds + i, n))
+                  for i in range(rounds)]))
+    assert _code_matrix._cache_size() == compiled
+
+
 _FAULT_PLANS = st.tuples(
     st.integers(min_value=0, max_value=2**31 - 1),
     st.sampled_from([0.0, 0.15, 0.3]),
@@ -388,6 +419,46 @@ def test_simulator_drivers_and_ledger_under_faults():
     assert res2.pilot_history == res.pilot_history
     k2 = {k for (_, _, k, _) in sim2.ledger.events}
     assert "seed_shares" in k2 and "mask_recovery" in k2
+
+
+def test_scan_calls_draw_the_fault_schedule_once_per_call():
+    """Two scan calls under a plan draw the host schedule once each, and
+    book exactly what the per-round ``codes`` draws booked; a plan-free
+    run draws none."""
+    from repro.core.fedpc import FedPCConfig
+    plan = FaultPlan(seed=3, drop_after_uplink=0.25,
+                     drop_before_uplink=0.1)
+    spec = PrivacySpec(mask_seed=5, modulus_bits=16, recovery_threshold=2)
+    cfg = FedPCConfig(n_workers=6, privacy=spec, faults=plan,
+                      tree=TreeSpec(fanout=3))
+
+    def two_calls(sim):
+        r1 = sim.run_fedpc_scan(rounds=3)
+        r2 = sim.run_fedpc_scan(rounds=3, state=r1.round_state)
+        return r1, r2
+
+    sim = _make_sim(cfg)
+    got = two_calls(sim)
+    assert sim.fault_schedule_draws == 2
+
+    ref = _make_sim(cfg)
+    ref._fault_codes = lambda t0, n_rounds: np.stack(
+        [np.asarray(plan.codes(t0 + i, ref.n)) for i in range(n_rounds)])
+    want = two_calls(ref)
+    assert ref.fault_schedule_draws == 0
+    assert sim.ledger.events == ref.ledger.events
+    assert {k for (_, _, k, _) in sim.ledger.events} >= {
+        "seed_shares", "mask_recovery"}
+    for g, w in zip(got, want):
+        assert g.costs == w.costs
+        assert g.bytes_per_round == w.bytes_per_round
+        assert g.recovery_bytes_per_round == w.recovery_bytes_per_round
+        assert g.pilot_history == w.pilot_history
+
+    cfg0 = FedPCConfig(n_workers=6, privacy=spec, tree=TreeSpec(fanout=3))
+    sim0 = _make_sim(cfg0)
+    sim0.run_fedpc_scan(rounds=3)
+    assert sim0.fault_schedule_draws == 0
 
 
 # ---------------------------------------------------------------------------
