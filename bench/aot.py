@@ -23,7 +23,7 @@ def compile_cell(name: str) -> dict:
     from jax.sharding import SingleDeviceSharding
 
     from bench.harness import gen, spec
-    from bench.harness.simcell import SimCell
+    from bench.drivers.simulator import SimCell
     from repro.core import flat as fl
     from repro.fed import rounds as rd
     from repro.telemetry import record as tmr

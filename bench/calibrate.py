@@ -3,7 +3,8 @@
     python3 bench/calibrate.py --workload <cell> --seeds 11 12 13 ... \
         [--control-seeds 3] [--out FILE]
 
-Runs on the chip, in one process. For every seed it makes the cell's own
+Runs on the chip, in one process, with the cell's own driver and chips.
+For every seed it makes the cell's own
 set-up (the program's first calls, exactly as a run makes them), frees
 the program and replays those rounds with the plain float32 reference:
 that gives the program's readings of every compared number (the lower
@@ -55,10 +56,10 @@ def main(argv=None) -> int:
         return 2
     from bench.harness import spec
     from bench.harness.check import _included, compare, explain
-    from bench.harness.simcell import SimCell
 
     wl = spec.workload(args.workload)
     cfg = spec.config(wl["config"])
+    make_cell = spec.driver(wl["driver"])
     limits = wl["limits"]
     arch, s_len = cfg["arch"], wl["seq_len"]
     out = open(args.out, "w") if args.out else None
@@ -72,7 +73,7 @@ def main(argv=None) -> int:
 
     for i, seed in enumerate(args.seeds):
         t0 = time.perf_counter()
-        cell = SimCell(wl, cfg, seed)
+        cell = make_cell(wl, cfg, seed)
         cell.setup()
         cell.release()
         ref = cell.reference(limits)

@@ -21,39 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness import gen
+from bench.harness import gen, spec
+from bench.harness.cells import arch_config, check_param_tree, diff_norms
 from bench.harness.check import compare
 from bench.reference import fedpc as ref_fedpc
 
 
 def _say(msg: str) -> None:
     print(f"bench: {msg}", file=sys.stderr, flush=True)
-
-
-def _arch_config(arch: dict):
-    from repro.configs.base import ArchConfig
-    kw = dict(arch)
-    kw["pattern"] = tuple(tuple(b) for b in kw["pattern"])
-    return ArchConfig(**kw)
-
-
-def _reference_module(name: str):
-    import importlib
-    return importlib.import_module(f"bench.reference.{name}")
-
-
-@jax.jit
-def _diff_norms(xs, ys):
-    return [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)
-                                        - y.astype(jnp.float32))))
-            for x, y in zip(xs, ys)]
-
-
-def diff_norms(a, b) -> dict:
-    """``{path: ||a - b||}`` per leaf, in one jitted call."""
-    fa = jax.tree_util.tree_flatten_with_path(a)[0]
-    vals = _diff_norms([x for _, x in fa], jax.tree_util.tree_leaves(b))
-    return {ref_fedpc.path_str(p): float(v) for (p, _), v in zip(fa, vals)}
 
 
 def _opt_norms(opt_state) -> dict:
@@ -67,7 +42,7 @@ class SimCell:
     def __init__(self, wl: dict, cfg: dict, seed: int):
         self.wl, self.cfg, self.seed = wl, cfg, seed
         self.arch = cfg["arch"]
-        self.ref = _reference_module(cfg["reference"])
+        self.ref = spec.reference(cfg["reference"])
         self.spec = self.ref.param_spec(self.arch)
         self.rounds = wl["rounds_per_call"]
         self.check_rounds = wl["check_rounds"]
@@ -118,16 +93,8 @@ class SimCell:
         from repro.models import build_model
         from repro.privacy.spec import PrivacySpec
 
-        model = build_model(_arch_config(self.arch))
-        have = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        want = gen.spec_shapes(self.spec)
-        if (jax.tree_util.tree_structure(have)
-                != jax.tree_util.tree_structure(want)
-                or jax.tree_util.tree_leaves(jax.tree_util.tree_map(
-                    lambda a, b: a.shape != b.shape, have, want))
-                .count(True)):
-            raise RuntimeError("the model's parameter tree differs from the "
-                               "reference's parameter spec")
+        model = build_model(arch_config(self.arch))
+        check_param_tree(model, self.spec)
         self.params0 = gen.make_weights(self.spec, self.seed)
         loss_fn = jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, {"tokens": jnp.asarray(b[0])}),
@@ -211,17 +178,18 @@ class SimCell:
                 "rounds": calls * self.rounds,
                 "call_s": np.diff([t0] + ends).tolist()}
 
-    def wire_bytes_per_round(self) -> float | None:
-        """Mean bytes a round of the window's calls put on the wire, as the
-        simulator's own ledger counts them (data plane plus recovery)."""
-        return (float(np.mean(self.window_bytes)) if self.window_bytes
-                else None)
+    def program_counters(self) -> dict:
+        """``wire_bytes_per_round``: mean bytes a round of the window's
+        calls put on the wire, as the simulator's own ledger counts them
+        (data plane plus recovery); absent before the window."""
+        if not self.window_bytes:
+            return {}
+        return {"wire_bytes_per_round": float(np.mean(self.window_bytes))}
 
-    def kernel_scopes(self) -> dict:
-        """``trace.kernel_scopes`` of the round programs compiled so far."""
-        from bench.harness import trace
-        return trace.kernel_scopes(p.compiled.as_text()
-                                   for p in self.sim.scan_programs.values())
+    def hlo_texts(self) -> list:
+        """The HLO texts of the round programs compiled so far."""
+        return [p.compiled.as_text()
+                for p in self.sim.scan_programs.values()]
 
     def release(self):
         """Free the program's device state before the reference runs."""
@@ -254,3 +222,6 @@ class SimCell:
 
     def check(self, limits: dict) -> dict:
         return compare(self.prog, self.reference(limits))
+
+
+Cell = SimCell

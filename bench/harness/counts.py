@@ -1,10 +1,13 @@
 """Operations and bytes that the work needs, from shapes alone.
 
-``train_flops_per_token``: model FLOPs of one training token, forward and
-backward (3x the forward's multiply-adds, counted as 2 FLOPs each),
-recomputation not counted, the output head counted whether tied or not.
-Attention counts its causal half of the score matrix; the recurrent cells
-count the work of their recurrences.
+The shared terms of a model's FLOP count, as forward multiply-adds counted
+as 2 FLOPs each, per token. Each configuration's reference
+(``bench/reference/<name>.py``) adds up its own layers from these in its
+``train_flops_per_token(arch, seq_len)``: model FLOPs of one training
+token, forward and backward (3x the forward), recomputation not counted,
+the output head counted whether tied or not. Attention counts its causal
+half of the score matrix; the recurrent cells count the work of their
+recurrences.
 
 ``hlo_bytes``: the HBM bytes one launch of a kernel has to read and
 write: every operand read once and every result written once, from the
@@ -19,44 +22,39 @@ TYPE_RE = re.compile(r"\b(pred|[subf](?:8|16|32|64)|bf16)\[([\d,]*)\]")
 DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
                "f64": 8}
+BACKWARD = 3.0        # forward plus backward, in units of the forward
 
 
-def _forward_flops_per_token(arch: dict, seq_len: int) -> float:
-    d, v = arch["d_model"], arch["vocab"]
-    n_units = arch["n_layers"] // len(arch["pattern"])
-    per_unit = 0.0
-    for mixer, ffn in arch["pattern"]:
-        if mixer == "mlstm":
-            h = arch["n_heads"]
-            di = int(arch["lstm_proj_factor"] * d) // h * h
-            dh = di // h
-            per_unit += 2 * d * 2 * di            # up-projection
-            per_unit += 3 * 2 * di * di            # q, k, v
-            per_unit += 2 * di * 2 * h             # input / forget gates
-            per_unit += 2 * di * d                 # down-projection
-            # recurrence per head: C <- f C + i v k^T, n <- f n + i k,
-            # C q and n.q
-            per_unit += h * (3 * dh * dh + 2 * dh * dh + 5 * dh)
-        elif mixer == "slstm":
-            per_unit += 2 * d * 4 * d              # input gates
-            per_unit += 2 * d * 4 * d              # recurrent gates
-            per_unit += 2 * d * d                  # output projection
-        elif mixer == "attn":
-            hq, hk, dh = arch["n_heads"], arch["n_kv_heads"], arch["head_dim"]
-            per_unit += 2 * d * (hq + 2 * hk) * dh + 2 * hq * dh * d
-            ctx = (seq_len + 1) / 2                # causal: mean keys seen
-            per_unit += 2 * 2 * hq * dh * ctx      # scores and values
-        else:
-            raise ValueError(f"no FLOP count for mixer {mixer!r}")
-        if ffn == "mlp":
-            per_unit += 3 * 2 * d * arch["d_ff"]   # SwiGLU
-        elif ffn != "none":
-            raise ValueError(f"no FLOP count for ffn {ffn!r}")
-    return n_units * per_unit + 2 * d * v           # output head
+def mlstm_flops(d: int, heads: int, proj_factor: float) -> float:
+    """One mLSTM block: up-projection, q/k/v, gates, down-projection, and
+    per head the recurrence ``C <- f C + i v k^T``, ``n <- f n + i k``,
+    ``C q`` and ``n.q``."""
+    di = int(proj_factor * d) // heads * heads
+    dh = di // heads
+    return (2 * d * 2 * di + 3 * 2 * di * di + 2 * di * 2 * heads
+            + 2 * di * d + heads * (3 * dh * dh + 2 * dh * dh + 5 * dh))
 
 
-def train_flops_per_token(arch: dict, seq_len: int) -> float:
-    return 3.0 * _forward_flops_per_token(arch, seq_len)
+def slstm_flops(d: int) -> float:
+    """One sLSTM block: input gates, recurrent gates, output projection."""
+    return 2 * d * 4 * d + 2 * d * 4 * d + 2 * d * d
+
+
+def attention_flops(d: int, q_heads: int, kv_heads: int, head_dim: int,
+                    seq_len: int) -> float:
+    """Grouped-query causal attention: projections, then scores and values
+    over the mean number of keys a query sees."""
+    proj = 2 * d * (q_heads + 2 * kv_heads) * head_dim
+    proj += 2 * q_heads * head_dim * d
+    return proj + 2 * 2 * q_heads * head_dim * (seq_len + 1) / 2
+
+
+def swiglu_flops(d: int, d_ff: int) -> float:
+    return 3 * 2 * d * d_ff
+
+
+def head_flops(d: int, vocab: int) -> float:
+    return 2 * d * vocab
 
 
 def hlo_bytes(text: str) -> int:

@@ -4,8 +4,12 @@ Everything that belongs to one configuration, one cell or one per-layer
 metric is a file of its own under ``bench/``:
 
 * ``bench/configs/<config>.json``   — sizes, source, cuts, reference name;
+* ``bench/reference/<reference>.py`` — the plain model (``param_spec``,
+  ``loss``) and its ``train_flops_per_token(arch, seq_len)``;
 * ``bench/workloads/<cell>.json``   — traffic: wire, workers, token shapes,
   rounds per call, which driver runs it;
+* ``bench/drivers/<driver>.py``     — a ``Cell`` class that drives the
+  program for one cell;
 * ``bench/metrics/<metric>.py``     — a reader with ``read(ctx)``;
 * ``bench/peaks.json``              — the chip's peaks, by ``device_kind``;
 * ``BENCHMARK.json`` (repo root)    — which metrics each cell reports.
@@ -17,6 +21,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -51,17 +56,57 @@ def config(name: str, bench_dir: Path = BENCH_DIR) -> dict:
     return c
 
 
+def module(kind: str, name: str, bench_dir: Path = BENCH_DIR):
+    """The module ``bench/<kind>/<name>.py``, loaded from its file (once
+    per process and file)."""
+    path = bench_dir / kind / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no such file: {kind}/{name}.py under {bench_dir}")
+    mod_name = "bench_module_" + "".join(
+        c if c.isalnum() else "_" for c in str(path.resolve()))
+    if mod_name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[mod_name]
+            raise
+    return sys.modules[mod_name]
+
+
+def _attribute(kind: str, name: str, attr: str, bench_dir: Path):
+    mod = module(kind, name, bench_dir)
+    if not hasattr(mod, attr):
+        raise SpecError(f"{kind}/{name}.py has no {attr!r}")
+    return getattr(mod, attr)
+
+
 def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise SpecError(f"no reader for per-layer metric {name!r}")
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _attribute("metrics", name, "read", bench_dir)
+
+
+def driver(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``Cell`` class of ``bench/drivers/<name>.py``: built as
+    ``Cell(workload, config, seed)``, with ``setup()``, ``window(seconds)``,
+    ``call()``, ``hlo_texts()`` (of the compiled programs the calls run),
+    ``program_counters()`` (counts the program keeps, for ``ctx``),
+    ``release()``, ``check(limits)``, ``rounds`` and ``tokens_per_call``
+    a call."""
+    return _attribute("drivers", name, "Cell", bench_dir)
+
+
+def reference(name: str, bench_dir: Path = BENCH_DIR):
+    """The plain reference ``bench/reference/<name>.py``."""
+    return module("reference", name, bench_dir)
+
+
+def flops_counter(name: str, bench_dir: Path = BENCH_DIR):
+    """``train_flops_per_token(arch, seq_len)`` of a configuration's
+    reference."""
+    return _attribute("reference", name, "train_flops_per_token", bench_dir)
 
 
 def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> dict:

@@ -17,11 +17,24 @@ clock, on the same timeline). Per device:
   (``kernel_scopes``); a Mosaic kernel outside a wire scope is not a wire
   kernel.
 
-Idle gaps inside the window are attributed to the innermost
-``bench/...`` host span that covers their midpoint.
+Idle gaps inside the window are attributed to the innermost host span
+that covers their midpoint: the benchmark's ``bench/...`` spans and the
+program's own ``fed/...`` ``TraceAnnotation`` spans (``fed/scan`` and its
+children ``fed/scan/prepare``, ``/state``, ``/audit``, ``/schedules``,
+``/compile``, ``/dispatch``, ``/finish``, ``/wait``, ``/ledger``,
+``/trace``), on the device's clock.
+
+Every operation is also given the device scope it ran under: the
+``jax.named_scope`` names (``fed/train/optimizer``, ``fed/flatten``,
+``fed/unflatten``, ``wire/...``) in its instruction's metadata
+``op_name`` in the compiled programs' HLO text. A trace event names only
+its instruction, so the scope is looked up there, and only for events that
+ran inside one of those programs (the device's ``XLA Modules`` line):
+instruction names repeat across programs.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import re
 from dataclasses import dataclass, field
@@ -35,7 +48,12 @@ HLO_KERNEL_RE = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.-]+) = .*'
                            + re.escape(MOSAIC_MARK) + r'.*?op_name="([^"]*)"')
 CONTROL_RE = re.compile(r"^%?[\w.-]+ = .*? (while|conditional|call)\(")
 HLO_NAME_RE = re.compile(r"^%?([A-Za-z_-]+?)[.\d]*( = |$)")
+OP_NAME_RE = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.-]+) = .*?op_name="([^"]*)"')
+MODULE_RE = re.compile(r"^HloModule ([\w.-]+)")
 CALL_SPAN = "bench/call"
+SPAN_PREFIXES = ("bench/", "fed/")     # host spans that label idle gaps
+# Device scopes that name a layer, in the order they are tested.
+SCOPES = ("fed/train/optimizer/", "fed/flatten/", "fed/unflatten/")
 
 
 @dataclass
@@ -62,6 +80,10 @@ class Reduction:
     ops: list             # per device, [Op] inside the window
     gaps: list = field(default_factory=list)   # [(label, seconds)]
     host_spans: list = field(default_factory=list)
+    # [(op_name, Op)], all devices, containers left out: op_name "" where
+    # the instruction carries none, None where the operation ran in
+    # another program than the ones handed over (an eager call)
+    scoped: list = field(default_factory=list)
 
     @property
     def chips(self) -> int:
@@ -112,24 +134,60 @@ def kernel_scopes(hlo_texts) -> dict:
     return out
 
 
+def op_names(hlo_texts) -> tuple[set, dict]:
+    """(module names, ``{instruction: op_name}``) of compiled programs'
+    HLO texts; every instruction with metadata."""
+    modules, names = set(), {}
+    for text in hlo_texts:
+        for line in text.splitlines():
+            m = OP_NAME_RE.match(line)
+            if m:
+                names[m.group(1)] = m.group(2)
+            elif MODULE_RE.match(line):
+                modules.add(MODULE_RE.match(line).group(1))
+    return modules, names
+
+
 def _instruction(text: str) -> str:
     return text.partition(" = ")[0].strip().lstrip("%")
 
 
-def reduce_profile(pd, scopes: dict | None = None) -> Reduction:
+def _scoped(ops: list, runs: list, modules: set, names: dict) -> list:
+    """``[(op_name, Op)]`` of one device's operations, containers left
+    out. ``runs`` is the device's sorted ``[(start_s, end_s, module)]``;
+    a device whose trace has none is taken as running only ``modules``."""
+    out = []
+    for op in ops:
+        if op.is_control:
+            continue
+        if runs:
+            i = bisect.bisect_right(runs, (op.start, float("inf"), "")) - 1
+            if i < 0 or not (runs[i][0] <= op.start <= runs[i][1]
+                             and runs[i][2] in modules):
+                out.append((None, op))
+                continue
+        out.append((names.get(_instruction(op.name), ""), op))
+    return out
+
+
+def reduce_profile(pd, hlo_texts=()) -> Reduction:
     """Reduce a ``ProfileData`` (or any object with the same shape);
-    ``scopes`` is ``kernel_scopes`` of the programs that ran."""
-    scopes = scopes or {}
+    ``hlo_texts`` are the ``as_text()`` of the compiled programs that ran
+    in the window."""
+    hlo_texts = list(hlo_texts)
+    scopes = kernel_scopes(hlo_texts)
+    modules, names = op_names(hlo_texts)
     spans, devices = [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
-            ops_line = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-            if ops_line:
-                devices.append((plane.name, ops_line[0]))
+            lines = {ln.name: ln for ln in plane.lines}
+            if "XLA Ops" in lines:
+                devices.append((plane.name, lines["XLA Ops"],
+                                lines.get("XLA Modules")))
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 for ev in ln.events:
-                    if ev.name.startswith("bench/"):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         spans.append((ev.name, ev.start_ns * 1e-9,
                                       (ev.start_ns + ev.duration_ns) * 1e-9))
     calls = [s for s in spans if s[0] == CALL_SPAN]
@@ -138,8 +196,8 @@ def reduce_profile(pd, scopes: dict | None = None) -> Reduction:
                          f"{len(devices)} device op lines")
     w0 = min(s for _, s, _ in calls)
     w1 = max(e for _, _, e in calls)
-    busy, ops_all, gaps = [], [], []
-    for _name, line in sorted(devices):
+    busy, ops_all, gaps, scoped = [], [], [], []
+    for _name, line, mods in sorted(devices, key=lambda d: d[0]):
         ops, iv = [], []
         for ev in line.events:
             s = ev.start_ns * 1e-9
@@ -154,9 +212,14 @@ def reduce_profile(pd, scopes: dict | None = None) -> Reduction:
         busy.append(union_length(iv))
         ops_all.append(ops)
         gaps.extend(_gaps(iv, w0, w1, spans))
+        runs = sorted((ev.start_ns * 1e-9,
+                       (ev.start_ns + ev.duration_ns) * 1e-9,
+                       ev.name.split("(")[0])
+                      for ev in (mods.events if mods else ()))
+        scoped.extend(_scoped(ops, runs, modules, names))
     gaps.sort(key=lambda g: -g[1])
     return Reduction(window_s=w1 - w0, busy_s=busy, ops=ops_all,
-                     gaps=gaps, host_spans=spans)
+                     gaps=gaps, host_spans=spans, scoped=scoped)
 
 
 def _gaps(iv, w0, w1, spans):
@@ -217,3 +280,33 @@ def wire_ops(red: Reduction) -> list:
     """``[(bytes, seconds)]`` of every wire launch, all devices."""
     return [(hlo_bytes(op.name), op.dur)
             for ops in red.ops for op in ops if op.is_wire]
+
+
+def idle_by_span(red: Reduction) -> dict:
+    """``{label: seconds}`` of the window's idle gaps by the innermost
+    span at their midpoint, mean over the devices."""
+    out: dict = {}
+    for label, sec in red.gaps:
+        out[label] = out.get(label, 0.0) + sec / red.chips
+    return out
+
+
+def scope_of(op_name) -> str:
+    """The program scope an operation's ``op_name`` lies in."""
+    if op_name is None:
+        return "(other programs)"
+    for mark in SCOPES:
+        if mark in op_name:
+            return mark.rstrip("/")
+    m = re.search(r"(?:^|/)(wire/[\w.-]+)/", op_name)
+    return m.group(1) if m else "(no scope)"
+
+
+def device_s_by_scope(red: Reduction) -> dict:
+    """``{scope_of(op_name): seconds}`` of the window's operations, summed
+    over the devices."""
+    out: dict = {}
+    for name, op in red.scoped:
+        scope = scope_of(name)
+        out[scope] = out.get(scope, 0.0) + op.dur
+    return out

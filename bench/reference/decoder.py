@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.harness import counts
 from bench.reference.layers import (P, dense, next_token_xent, rms_norm,
                                     silu, unit)
 
@@ -92,3 +93,15 @@ def loss(params: dict, tokens, arch: dict):
     head = (params["embed"].T if arch.get("tie_embeddings", False)
             else params["lm_head"])
     return next_token_xent(x @ head, tokens)
+
+
+def train_flops_per_token(arch: dict, seq_len: int) -> float:
+    """Model FLOPs of one training token (``bench/harness/counts.py``)."""
+    d = arch["d_model"]
+    per_block = (counts.attention_flops(d, arch["n_heads"],
+                                        arch["n_kv_heads"], arch["head_dim"],
+                                        seq_len)
+                 + counts.swiglu_flops(d, arch["d_ff"]))
+    n_units = arch["n_layers"] // len(arch["pattern"])
+    return counts.BACKWARD * (n_units * len(arch["pattern"]) * per_block
+                              + counts.head_flops(d, arch["vocab"]))

@@ -5,7 +5,8 @@ history ``P2 = P^{t-2}`` (zeros before round 2):
 
 1. every worker trains ``local_epochs`` epochs of its shard from ``P1``
    with its own optimizer, whose state it keeps between rounds; its cost
-   ``C_k`` is the mean loss of its local steps;
+   ``C_k`` is the mean loss of its local steps (the simulator's), or the
+   loss of its last local step (``build_fed_step``'s);
 2. the pilot ``k*`` maximises the goodness of Eq. (1): ``S_k / C_k`` in
    round 1, ``S_k (C_k^{t-1} - C_k)`` after, among the workers whose
    report is used;
@@ -160,7 +161,7 @@ def path_str(path) -> str:
 
 def replay(*, loss_fn, params0, shards, workers, wire: dict, rounds: int,
            opt_round: int, program_pilots, tol: float, dtype=jnp.float32,
-           drop_wire: bool = False):
+           drop_wire: bool = False, cost: str = "mean"):
     """Replay ``rounds`` rounds. ``workers[k]`` has ``optimizer``,
     ``local_epochs``, ``batch_size``, ``lr0``, ``lr_decay``,
     ``lr_decay_every`` and ``loader_seed``; ``shards[k]`` is its (n_k, S)
@@ -168,7 +169,9 @@ def replay(*, loss_fn, params0, shards, workers, wire: dict, rounds: int,
     (``pilot_choices``), the replay follows it, so one near tie does not
     send the two runs down different paths; with ``program_pilots=None``
     it follows its own choice. ``drop_wire`` leaves every worker's codes
-    out of Eq. (3), a fault the check has to catch.
+    out of Eq. (3), a fault the check has to catch. ``cost`` is
+    ``"mean"`` or ``"last"``: a worker's cost is the mean of its local
+    steps' losses, or its last step's.
 
     Returns ``costs`` and ``pilot_ok`` per round, the optimizer-state leaf
     norms of every worker after round ``opt_round``, the leaf norms of the
@@ -212,7 +215,8 @@ def replay(*, loss_fn, params0, shards, workers, wire: dict, rounds: int,
                     del g
                     losses.append(l)
                     steps[k] += 1
-            costs[k] = float(np.mean([float(x) for x in losses]))
+            costs[k] = (float(losses[-1]) if cost == "last"
+                        else float(np.mean([float(x) for x in losses])))
             locals_.append(q)
         alive = np.ones(n, bool)
         if wire.get("fault_p_after"):

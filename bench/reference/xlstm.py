@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.harness import counts
 from bench.reference.layers import (P, dense, next_token_xent, rms_norm,
                                     silu, unit)
 
@@ -136,3 +137,14 @@ def loss(params: dict, tokens, arch: dict):
     head = (params["embed"].T if arch.get("tie_embeddings", False)
             else params["lm_head"])
     return next_token_xent(x @ head, tokens)
+
+
+def train_flops_per_token(arch: dict, seq_len: int) -> float:
+    """Model FLOPs of one training token (``bench/harness/counts.py``)."""
+    d, h = arch["d_model"], arch["n_heads"]
+    per_unit = sum(counts.mlstm_flops(d, h, arch["lstm_proj_factor"])
+                   if mixer == "mlstm" else counts.slstm_flops(d)
+                   for mixer, _ffn in arch["pattern"])
+    n_units = arch["n_layers"] // len(arch["pattern"])
+    return counts.BACKWARD * (n_units * per_unit
+                              + counts.head_flops(d, arch["vocab"]))

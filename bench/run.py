@@ -3,7 +3,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``bench/workloads/<cell>.json``, its configuration in
-``bench/configs/``, and the metrics it reports in ``BENCHMARK.json``.
+``bench/configs/``, the driver that runs it in ``bench/drivers/``, and the
+metrics it reports in ``BENCHMARK.json``.
 Set-up (loading, weights and tokens from the seed, compilation, the first
 calls) is timed as ``setup_s``; then, with ``--trace 0``, the window calls
 the timed path until ``--seconds`` have passed and reports the end-to-end
@@ -64,13 +65,6 @@ def require_devices(chips: int):
     return devices[:chips]
 
 
-def make_cell(wl: dict, cfg: dict, seed: int):
-    if wl["driver"] == "simulator":
-        from bench.harness.simcell import SimCell
-        return SimCell(wl, cfg, seed)
-    raise ValueError(f"unknown driver {wl['driver']!r}")
-
-
 def peak_bytes(devices) -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in devices]
@@ -85,7 +79,7 @@ def traced_window(cell, calls: int):
     import jax
     from bench.harness import trace
 
-    scopes = cell.kernel_scopes()
+    texts = cell.hlo_texts()
     tmp = tempfile.mkdtemp(prefix="bench-trace-")
     try:
         opts = jax.profiler.ProfileOptions()
@@ -98,7 +92,7 @@ def traced_window(cell, calls: int):
                 del res
         finally:
             jax.profiler.stop_trace()
-        red = trace.reduce_profile(trace.load(tmp), scopes)
+        red = trace.reduce_profile(trace.load(tmp), texts)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
     return red, calls * cell.tokens_per_call, calls * cell.rounds
@@ -114,6 +108,8 @@ def main(argv=None) -> int:
     e2e, per_layer = spec.cell_metrics(bench, args.workload)
     wl = spec.workload(args.workload)
     cfg = spec.config(wl["config"])
+    make_cell = spec.driver(wl["driver"])
+    flops = spec.flops_counter(cfg["reference"])
 
     import jax
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
@@ -155,15 +151,17 @@ def main(argv=None) -> int:
             f"{win['rounds']} rounds, {win['tokens']} tokens; a call "
             f"{min(win['call_s']):.3f}-{max(win['call_s']):.3f} s")
     else:
-        from bench.harness import counts, trace
+        from bench.harness import trace
         red, tokens, rounds = traced_window(cell, wl["trace_calls"])
         attempted = rounds
         ctx = {"reduction": red, "tokens": tokens, "rounds": rounds,
                "peaks": peaks, "n_workers": len(wl["workers"]),
-               "flops_per_token": counts.train_flops_per_token(
-                   cfg["arch"], wl["seq_len"]),
+               "flops_per_token": flops(cfg["arch"], wl["seq_len"]),
                "wire_ops": trace.wire_ops(red),
-               "wire_bytes_per_round": cell.wire_bytes_per_round()}
+               "scoped_ops": red.scoped,
+               "device_s_by_scope": trace.device_s_by_scope(red),
+               "idle_by_span": trace.idle_by_span(red),
+               **cell.program_counters()}
         for m in per_layer:
             v = spec.metric_reader(m["name"])(ctx)
             if v is not None:

@@ -7,3 +7,8 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# four host devices for the mesh driver's cells; one-chip cells use the first
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=4").strip()

@@ -4,17 +4,18 @@ where the program itself comes out correct."""
 import jax.numpy as jnp
 import pytest
 
+from bench.harness import spec
 from bench.harness.check import compare, verdict
-from bench.harness.simcell import SimCell
 from bench.tests import tiny
 
 
-@pytest.mark.parametrize("pair", [tiny.tiny_xlstm, tiny.tiny_phi4],
-                         ids=["xlstm", "phi4"])
+@pytest.mark.parametrize("pair", [tiny.tiny_xlstm, tiny.tiny_phi4,
+                                  tiny.tiny_mesh],
+                         ids=["xlstm", "phi4", "mesh"])
 def test_control_fails_where_the_program_passes(pair):
     wl, cfg = tiny.with_changes(pair(), rounds_per_call=1, check_rounds=1,
                                 setup_calls=2)
-    cell = SimCell(wl, cfg, seed=2 ** 33 + 29)
+    cell = spec.driver(wl["driver"])(wl, cfg, seed=2 ** 33 + 29)
     cell.setup()
     cell.release()
     ref = cell.reference(wl["limits"])

@@ -1,7 +1,17 @@
-"""FLOP and byte counts against hand counts at small shapes."""
+"""FLOP and byte counts against hand counts at small shapes, and the
+committed configurations' counts to the FLOP."""
+import json
+from pathlib import Path
+
 import pytest
 
-from bench.harness import counts
+from bench.harness import counts, spec
+
+BENCH = Path(__file__).resolve().parents[1]
+
+
+def _flops(reference, arch, seq_len):
+    return spec.flops_counter(reference)(arch, seq_len)
 
 
 def test_decoder_flops_by_hand():
@@ -13,7 +23,7 @@ def test_decoder_flops_by_hand():
     # scores+values: 2*2*2 heads*4 dh*(3+1)/2 = 64; SwiGLU 3*2*8*16 = 768
     # head 2*8*10 = 160
     fwd = 384 + 64 + 768 + 160
-    assert counts.train_flops_per_token(arch, s) == 3 * fwd
+    assert _flops("decoder", arch, s) == 3 * fwd
 
 
 def test_xlstm_flops_by_hand():
@@ -24,18 +34,33 @@ def test_xlstm_flops_by_hand():
     mlstm = (2 * 4 * 2 * di + 3 * 2 * di * di + 2 * di * 2 * h
              + 2 * di * 4 + h * (5 * dh * dh + 5 * dh))
     slstm = 2 * 4 * 16 + 2 * 4 * 16 + 2 * 4 * 4
-    assert counts.train_flops_per_token(arch, 16) == 3 * (
+    assert _flops("xlstm", arch, 16) == 3 * (
         mlstm + slstm + 2 * 4 * 6)
 
 
 def test_published_sizes_are_plausible():
     """The xLSTM head alone is 2*1024*50304 FLOPs a token forward."""
-    import json
-    from pathlib import Path
-    bench = Path(__file__).resolve().parents[1]
-    x = json.loads((bench / "configs/xlstm-350m-1p.json").read_text())
-    f = counts.train_flops_per_token(x["arch"], 2048)
+    x = json.loads((BENCH / "configs/xlstm-350m-1p.json").read_text())
+    f = _flops(x["reference"], x["arch"], 2048)
     assert 3 * 2 * 1024 * 50304 < f < 3 * 2 * 131_359_752 * 1.2
+
+
+@pytest.mark.parametrize("config,seq_len,want", [
+    ("xlstm-350m-1p", 2048, 494_794_752),
+    ("phi4-mini-1l", 256, 1_069_664_256)])
+def test_committed_counts_do_not_move(config, seq_len, want):
+    """The counts every cell's ``mfu`` has used since the first benchmark,
+    now read from the configuration's reference, to the FLOP."""
+    cfg = spec.config(config)
+    assert _flops(cfg["reference"], cfg["arch"], seq_len) == want
+
+
+def test_a_reference_without_a_count_is_refused(tmp_path):
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "reference" / "plain.py").write_text(
+        "def loss(p, t, arch):\n    return 0.0\n")
+    with pytest.raises(spec.SpecError, match="reference/plain.py"):
+        spec.flops_counter("plain", tmp_path)
 
 
 def test_hlo_bytes_by_hand():

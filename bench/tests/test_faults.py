@@ -104,6 +104,73 @@ def test_broken_path_is_caught(monkeypatch, capsys, fault):
     assert res["correct"] is False, res["checks"]
 
 
+def _wrap_fed_step(monkeypatch, wrap):
+    """Break the mesh cell's step: ``wrap(step)`` in place of each
+    ``build_fed_step`` result."""
+    from repro.fed import distributed
+    orig = distributed.build_fed_step
+
+    def broken(*a, **k):
+        return wrap(orig(*a, **k))
+    monkeypatch.setattr(distributed, "build_fed_step", broken)
+
+
+def _mesh_state_unchanged(monkeypatch):
+    def wrap(step):
+        def frozen(state, opt, batch, sizes, mask=None):
+            _new, opt, metrics = step(state, opt, batch, sizes, mask)
+            return state, opt, metrics
+        return frozen
+    _wrap_fed_step(monkeypatch, wrap)
+
+
+def _mesh_half_batch(monkeypatch):
+    def wrap(step):
+        def half(state, opt, batch, sizes, mask=None):
+            toks = batch["tokens"]
+            return step(state, opt, {"tokens": toks[..., :toks.shape[-1]
+                                                   // 2]}, sizes, mask)
+        return half
+    _wrap_fed_step(monkeypatch, wrap)
+
+
+def _mesh_token_altered(monkeypatch):
+    def wrap(step):
+        def altered(state, opt, batch, sizes, mask=None):
+            toks = batch["tokens"]
+            mid = toks.shape[-1] // 2
+            toks = toks.at[..., mid].set((toks[..., mid] + 1) % 7)
+            return step(state, opt, {"tokens": toks}, sizes, mask)
+        return altered
+    _wrap_fed_step(monkeypatch, wrap)
+
+
+def _mesh_no_exchange(monkeypatch):
+    """Every chip keeps its own codes and weights: the all-gather of the
+    codes and the all-reduce of the pilot's weights left out."""
+    import jax.numpy as jnp
+    chips = tiny.tiny_mesh()[0]["chips"]
+    monkeypatch.setattr(jax.lax, "all_gather",
+                        lambda x, axis_name, **k: jnp.broadcast_to(
+                            x, (chips,) + x.shape))
+    monkeypatch.setattr(jax.lax, "psum", lambda x, axis_name, **k: x)
+
+
+@pytest.mark.parametrize("fault", [None, _mesh_state_unchanged,
+                                   _mesh_half_batch, _mesh_no_exchange,
+                                   _mesh_token_altered],
+                         ids=["sound", "state-unchanged", "half-batch",
+                              "no-exchange", "token-altered"])
+def test_mesh_cell_on_four_devices(monkeypatch, capsys, fault):
+    """The four-chip cell's driver on four host devices: sound, correct;
+    with its step broken underneath, not correct."""
+    if fault is not None:
+        fault(monkeypatch)
+    res = _drive(monkeypatch, capsys, tiny.tiny_mesh(),
+                 check_rounds=XLSTM_ROUNDS)
+    assert res["correct"] is (fault is None), res["checks"]
+
+
 def test_broken_secure_aggregation_is_caught(monkeypatch, capsys):
     _no_wire(monkeypatch)
     res = _drive(monkeypatch, capsys, tiny.tiny_phi4())

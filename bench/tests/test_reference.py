@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from bench.harness import gen
-from bench.harness.simcell import SimCell, _arch_config, _reference_module
+from bench.harness import spec as bench_spec
+from bench.harness.cells import arch_config
 from bench.reference import fedpc as ref_fedpc
 from bench.tests import tiny
 
@@ -16,10 +17,10 @@ from bench.tests import tiny
 def test_reference_loss_and_grads_match_the_model(pair):
     from repro.models import build_model
     _wl, cfg = pair()
-    ref = _reference_module(cfg["reference"])
+    ref = bench_spec.reference(cfg["reference"])
     spec = ref.param_spec(cfg["arch"])
     params = gen.make_weights(spec, 2 ** 40 + 3)
-    model = build_model(_arch_config(cfg["arch"]))
+    model = build_model(arch_config(cfg["arch"]))
     toks = jnp.asarray(gen.token_shards(5, 1, 2, 16, cfg["arch"]["vocab"],
                                         4)[0])
     with jax.default_matmul_precision("highest"):
@@ -36,7 +37,7 @@ def test_reference_loss_and_grads_match_the_model(pair):
 
 def test_weights_and_tokens_follow_the_seed():
     _wl, cfg = tiny.tiny_xlstm()
-    spec = _reference_module("xlstm").param_spec(cfg["arch"])
+    spec = bench_spec.reference("xlstm").param_spec(cfg["arch"])
     a = gen.make_weights(spec, 2 ** 33 + 1)
     b = gen.make_weights(spec, 2 ** 33 + 1)
     c = gen.make_weights(spec, 2 ** 33 + 2)
@@ -58,16 +59,21 @@ def test_fault_schedule_copy_matches_the_program():
         assert np.array_equal(ref_fedpc.fault_alive(15, t, 4, 0.25), want)
 
 
-@pytest.mark.parametrize("pair", [tiny.tiny_xlstm, tiny.tiny_phi4],
-                         ids=["plain", "secagg-tree-drop"])
-def test_round_replay_follows_the_program(pair):
-    """The program's first round and the reference's replay of it agree
-    far inside the committed limits on the CPU. (Later rounds drift apart
-    by the chaos of Adam steps on gradients near zero; one round is the
-    test of the arithmetic.)"""
-    wl, cfg = tiny.with_changes(pair(), rounds_per_call=1, check_rounds=1,
-                                setup_calls=2)
-    cell = SimCell(wl, cfg, seed=2 ** 32 + 17)
+@pytest.mark.parametrize("pair,rounds", [(tiny.tiny_xlstm, 1),
+                                         (tiny.tiny_phi4, 1),
+                                         (tiny.tiny_mesh, 2)],
+                         ids=["plain", "secagg-tree-drop", "mesh-4-devices"])
+def test_round_replay_follows_the_program(pair, rounds):
+    """The program's first rounds and the reference's replay of them agree
+    far inside the committed limits on the CPU (the mesh cell's driver on
+    four host devices, one worker each). (Later rounds drift apart by the
+    chaos of Adam steps on gradients near zero; one round is the test of
+    the arithmetic, two of the mesh's sync, whose round-1 codes are empty
+    at its wire's alpha1.)"""
+    wl, cfg = tiny.with_changes(pair(), rounds_per_call=1,
+                                check_rounds=rounds,
+                                setup_calls=max(2, rounds))
+    cell = bench_spec.driver(wl["driver"])(wl, cfg, seed=2 ** 32 + 17)
     cell.setup()
     cell.release()
     from bench.harness.check import compare

@@ -74,7 +74,7 @@ def _profile():
 
 
 def _reduce():
-    return trace.reduce_profile(_profile(), trace.kernel_scopes([HLO]))
+    return trace.reduce_profile(_profile(), [HLO])
 
 
 def test_kernel_scopes_from_hlo_text():
@@ -140,3 +140,39 @@ def test_missing_spans_is_an_error():
     pd.planes[0].lines[0].events = []
     with pytest.raises(ValueError):
         trace.reduce_profile(pd)
+
+
+def test_collectives_by_their_opcode():
+    """``sync_collective_ms_per_round`` sums the collectives, synchronous
+    and asynchronous halves, whatever the instruction is called, and
+    nothing else; mean over the chips, per round."""
+    from bench.harness import spec
+    us = 1000.0
+    coll = [
+        "%psum.7 = f32[256576,512]{1,0:T(8,128)} all-reduce(f32[256576,512]"
+        "{1,0} %r), channel_id=1, replica_groups={{0,1,2,3}}",
+        "%all-reduce.4 = (f32[4]{0:T(128)}, f32[]{:T(128)}) all-reduce("
+        "f32[4]{0} %a, f32[] %b), channel_id=6",
+        "%all-gather-start.1 = (u8[1,64]{1,0}, u8[4,64]{1,0}) "
+        "all-gather-start(u8[1,64]{1,0} %p), dimensions={0}",
+        "%all-gather-done.1 = u8[4,64]{1,0} all-gather-done((u8[1,64]{1,0}, "
+        "u8[4,64]{1,0}) %all-gather-start.1)",
+        "%collective-permute.2 = f32[8]{0} collective-permute(f32[8]{0} %x)",
+    ]
+    other = ["%fusion.3 = f32[8]{0} fusion(f32[8]{0} %all-reduce.4), "
+             "kind=kLoop", UPLINK]
+    host = Plane("/host:CPU", [Line("python", [Ev("bench/call", 0,
+                                                   100 * us)])])
+    devs = [Plane(f"/device:TPU:{d}", [Line("XLA Ops", [
+        Ev(text, 10 * i * us, (d + 1) * us)
+        for i, text in enumerate(coll + other)])]) for d in range(2)]
+    red = trace.reduce_profile(type("PD", (), {"planes": [host] + devs})())
+    read = spec.metric_reader("sync_collective_ms_per_round")
+    # five collectives of 1 us on device 0 and of 2 us on device 1, over
+    # 2 chips and 4 rounds
+    assert read({"reduction": red, "rounds": 4}) == pytest.approx(
+        1e3 * (5 * 1 + 5 * 2) * 1e-6 / 2 / 4)
+    no_coll = trace.reduce_profile(type("PD", (), {"planes": [host, Plane(
+        "/device:TPU:0", [Line("XLA Ops", [Ev(t, 0, us) for t in other])])
+    ]})())
+    assert read({"reduction": no_coll, "rounds": 4}) is None

@@ -29,6 +29,14 @@ def tiny_phi4():
     return wl, cfg
 
 
+def tiny_mesh():
+    """The four-chip cell at the tiny xLSTM's sizes, on four host devices."""
+    cfg = tiny_xlstm()[1]
+    wl = _load("workloads", "xlstm-1p.xsilo.4chip")
+    wl.update(seq_len=16)
+    return wl, cfg
+
+
 def with_changes(pair, **wl_kw):
     wl, cfg = copy.deepcopy(pair)
     wl.update(wl_kw)
